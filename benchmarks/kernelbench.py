@@ -630,10 +630,11 @@ def bench_conv_tiled(results):
         _, nb, _ = band_geometry(ho, wo, rpb, kernel, stride)
 
         untiled = functools.partial(bitserial_conv, w_packed=w_packed,
-                                    kernel=kernel, stride=stride, w_bits=pw)
+                                    kernel=kernel, stride=stride, w_bits=pw,
+                                    interpret=True)
         banded = functools.partial(bitserial_conv, w_packed=w_packed,
                                    kernel=kernel, stride=stride, w_bits=pw,
-                                   rows_per_band=rpb)
+                                   rows_per_band=rpb, interpret=True)
         np.testing.assert_array_equal(np.asarray(untiled(x)),
                                       np.asarray(banded(x)))  # bit-exact
         t_untiled = _time(untiled, x)

@@ -284,20 +284,21 @@ class PallasBackend(Backend):
             bm, bn, bk = _pallas_blocks(m, n, k)
             return bitserial_matmul(xq, w_packed, w_bits=w_bits, bm=bm,
                                     bn=bn, bk=bk, interpret=self.interpret)
-        # Static weight-group trimming reuses the dynamic-precision kernel
-        # verbatim: the packed operand here IS the weights, the N-tile is
-        # the filter group, and the scalar-prefetch counts are the
-        # pack-time constants from the plan — pl.when skips whole
-        # (plane x filter-group) grid steps, so on TPU the dead planes'
-        # tiles are never even fetched from HBM. Ragged last group: pad N
-        # with zero columns (they fit any count), slice the result back.
+        # Static weight-group trimming reuses the dynamic-precision kernel:
+        # the packed operand here IS the weights and the counts are the
+        # pack-time constants from the plan, one per group of w_group
+        # filters on 128-wide N-tiles — pl.when skips whole
+        # (plane x N-tile) grid steps above the tile's largest count, so
+        # on TPU the dead planes' tiles are never fetched from HBM. Ragged
+        # last group: pad N with zero columns (they fit any count), slice
+        # the result back.
         npad = (-n) % w_group
         wp = jnp.pad(w_packed, ((0, 0), (0, 0), (0, npad))) if npad \
             else w_packed
-        bm, _, bk = _pallas_blocks(m, n + npad, k)
+        bm, bn, bk = _pallas_blocks(m, n + npad, k)
         y = bitserial_matmul_dynamic(
             xq, wp, jnp.asarray(w_counts, jnp.int32), w_bits=w_bits,
-            bm=bm, bn=w_group, bk=bk, interpret=self.interpret)
+            group=w_group, bm=bm, bn=bn, bk=bk, interpret=self.interpret)
         return y[:, :n] if npad else y
 
     def matmul_planes_dynamic(self, xq, w_packed, plane_counts, *, w_bits,
@@ -320,17 +321,18 @@ class PallasBackend(Backend):
                                   w_bits=w_bits, rows_per_band=conv_tile,
                                   interpret=self.interpret)
         # Static weight-group trimming: the wgroup kernel's grid gains the
-        # serial weight-plane axis, gated per filter group by the
-        # pack-time scalar-prefetch counts. Ragged last group: pad N with
-        # zero columns (they fit any count), slice the result back.
+        # serial weight-plane axis, gated per N-tile by the pack-time
+        # scalar-prefetch counts. Ragged last group: pad N with zero
+        # columns (they fit any count), slice the result back.
         n = w_packed.shape[-1]
         npad = (-n) % w_group
         wp = jnp.pad(w_packed, ((0, 0), (0, 0), (0, npad))) if npad \
             else w_packed
         y = bitserial_conv_wgroup(
             xq.astype(jnp.int8), wp, jnp.asarray(w_counts, jnp.int32),
-            kernel=kernel, stride=stride, w_bits=w_bits, bn=w_group,
-            rows_per_band=conv_tile, interpret=self.interpret)
+            kernel=kernel, stride=stride, w_bits=w_bits, w_group=w_group,
+            bn=_pallas_blocks(1, n + npad, 1)[1], rows_per_band=conv_tile,
+            interpret=self.interpret)
         return y[..., :n] if npad else y
 
     def conv_planes_dynamic(self, xq, w_packed, counts, *, kernel, stride,
@@ -373,11 +375,16 @@ class PallasBackend(Backend):
 
 # -- Guarded dispatch -------------------------------------------------------
 
-# Degradation order: fastest substrate first, the always-works XLA oracle
-# last. A GuardedBackend's chain is the suffix of this list starting
-# after its inner backend (an unknown/out-of-tree inner falls straight
-# to the built-ins).
-DEFAULT_FALLBACK_CHAIN = ("pallas_tpu", "pallas_interpret", "xla")
+def default_fallback_chain() -> tuple[str, ...]:
+    """Degradation order: fastest substrate first, the always-works XLA
+    oracle last. A GuardedBackend's chain is the suffix of this order
+    starting after its inner backend (an unknown/out-of-tree inner falls
+    straight to the built-ins). On a TPU the Pallas interpreter is never
+    a recovery — it runs the kernel on the host, orders of magnitude
+    slower — so a kernel Mosaic refuses falls straight to XLA there."""
+    if jax.default_backend() == "tpu":
+        return ("pallas_tpu", "xla")
+    return ("pallas_tpu", "pallas_interpret", "xla")
 
 # The uniform op surface a Backend exposes (= what a GuardedBackend guards).
 BACKEND_OPS = ("matmul_planes", "matmul_planes_dynamic", "conv_planes",
@@ -434,7 +441,7 @@ class GuardedBackend(Backend):
         self.interpret = inner.interpret
         self.vmem_budget = inner.vmem_budget
         if chain is None:
-            names = list(DEFAULT_FALLBACK_CHAIN)
+            names = list(default_fallback_chain())
             if inner.name in names:
                 names = names[names.index(inner.name) + 1:]
             chain = [get_backend(n) for n in names]
@@ -658,6 +665,7 @@ def resolve_backend(backend=None, use_pallas: bool | None = None,
     ``backend`` may be a Backend, a registered name, or None — in which
     case the legacy ``use_pallas``/``interpret`` booleans pick among the
     built-ins (kept for ad-hoc tooling; plans carry a Backend object).
+    Only an explicit ``interpret=True`` selects the interpreter.
     """
     if isinstance(backend, Backend):
         return backend
@@ -666,8 +674,7 @@ def resolve_backend(backend=None, use_pallas: bool | None = None,
     if backend is not None:
         raise TypeError(f"backend must be a Backend or name, got {backend!r}")
     if use_pallas:
-        return get_backend("pallas_interpret" if (interpret is None or interpret)
-                           else "pallas_tpu")
+        return get_backend("pallas_interpret" if interpret else "pallas_tpu")
     return get_backend("xla")
 
 

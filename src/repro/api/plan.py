@@ -189,7 +189,7 @@ class ExecutionPlan:
         """
         import numpy as np
 
-        from repro.core import bitpack, weightgroups
+        from repro.core import weightgroups
         if not getattr(self.policy, "w_group", 0):
             return
         memo = {}   # (name, w_group) -> counts: conv layers also carry a
@@ -203,10 +203,8 @@ class ExecutionPlan:
                 continue
             counts = memo.get((name, lp.w_group))
             if counts is None:
-                w_bits = wp.shape[0]
-                wq = bitpack.unpack_weights(wp, w_bits)
                 counts = tuple(int(v) for v in np.asarray(
-                    weightgroups.weight_group_counts(wq, w_bits,
+                    weightgroups.packed_group_counts(wp, wp.shape[0],
                                                      lp.w_group)))
                 memo[(name, lp.w_group)] = counts
             self.set_weight_counts(name, kind, counts)

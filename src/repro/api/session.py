@@ -133,7 +133,8 @@ class ServingSession:
         if self._classify is not None:
             from repro.models import cnn
             cfg, plan = self.cfg, self.plan
-            classify = jax.jit(lambda p, x: cnn.forward(p, cfg, x, plan))
+            classify = jax.jit(lambda p, x: cnn.forward(p, cfg, x, plan),
+                               **_JIT)
             return dataclasses.replace(self, _classify=classify)
         from repro.models import model as M
         cache_specs = M.cache_spec_tree(self.cfg) \
@@ -159,6 +160,17 @@ class ServingSession:
         return dyn.dynamic_stats(xq, bits, lp.group_size)
 
 
+# Serving programs round each value to the dtype the model states. By
+# default XLA may keep f32 where the program rounds to bf16 ("excess
+# precision"), and where it does so follows fusion, which differs between
+# a Mosaic kernel and XLA's own dot. Per-row int8 activation quantization
+# then turns a one-ulp difference into another int8 code: on a TPU v5e
+# the pallas_tpu and xla streams of qwen3-1.7b parted within 10 tokens
+# by default, and were bit-identical with this option. The byte compares
+# against the xla route (shadow audit, guarded fallback) rely on it.
+_JIT = {"compiler_options": {"xla_allow_excess_precision": False}}
+
+
 def _jit_lm(cfg, plan, mesh, param_specs, cache_specs):
     """Jit the prefill/decode pair, with resolved shardings when a mesh is
     given. ``plan`` is an ExecutionPlan
@@ -172,8 +184,8 @@ def _jit_lm(cfg, plan, mesh, param_specs, cache_specs):
         return M.decode_step(params, cfg, token, pos, cache, plan)
 
     if mesh is None:
-        return (jax.jit(prefill_fn),
-                jax.jit(decode_fn, donate_argnums=(3,)))
+        return (jax.jit(prefill_fn, **_JIT),
+                jax.jit(decode_fn, donate_argnums=(3,), **_JIT))
     from jax.sharding import PartitionSpec as PS
     from repro.dist.sharding import resolve_tree
     psh = resolve_tree(param_specs, mesh)
@@ -183,11 +195,11 @@ def _jit_lm(cfg, plan, mesh, param_specs, cache_specs):
     # 4th entry: img_embeds (None = unconstrained; empty pytree for LMs).
     prefill_j = jax.jit(prefill_fn,
                         in_shardings=(psh, toks_sh, csh, None),
-                        out_shardings=(None, csh))
+                        out_shardings=(None, csh), **_JIT)
     decode_j = jax.jit(decode_fn,
                        in_shardings=(psh, tok_sh, None, csh),
                        out_shardings=(None, csh),
-                       donate_argnums=(3,))
+                       donate_argnums=(3,), **_JIT)
     return prefill_j, decode_j
 
 
@@ -231,7 +243,8 @@ def compile(cfg, policy: Optional[PrecisionPolicy] = None,
             # (CNN param keys ARE the layer names), before classify
             # traces; the hot path only ever reads plan metadata.
             plan.record_weight_groups(params)
-        classify = jax.jit(lambda p, x: cnn.forward(p, cfg, x, plan))
+        classify = jax.jit(lambda p, x: cnn.forward(p, cfg, x, plan),
+                           **_JIT)
         sess = ServingSession(cfg=cfg, plan=plan, params=params, specs=specs,
                               _classify=classify)
         if mode in _SERVING_MODES:

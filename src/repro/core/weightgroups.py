@@ -23,6 +23,8 @@ floor (one plane of zeros still executes — counts never reach 0).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -46,6 +48,17 @@ def weight_group_counts(wq: jax.Array, bits: int,
     g = wq.reshape(k, (n + pad) // group_size, group_size)
     eff = q.effective_bits(g, axis=(0, 2))
     return jnp.minimum(eff, bits).astype(jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def packed_group_counts(w_packed: jax.Array, bits: int,
+                        group_size: int) -> jax.Array:
+    """:func:`weight_group_counts` of packed planes uint8 [bits, K/8, N],
+    as one program: unpacked op by op, a 2048 x 151936 head would stage
+    10 GB of int32 plane temporaries."""
+    from repro.core import bitpack
+    return weight_group_counts(bitpack.unpack_weights(w_packed, bits), bits,
+                               group_size)
 
 
 def truncate_signed(v: jax.Array, counts: jax.Array) -> jax.Array:

@@ -9,45 +9,11 @@ sequence parallelism for a decode cell).
 Resolution is idempotent: physical names and ``None`` pass through, so a
 resolved spec can be resolved again (the dryrun driver does this when it
 re-enters with a different mesh kind).
-
-Also installs two tiny forward-compat shims for the jax pinned in this
-container (0.4.37): ``jax.set_mesh`` (the Mesh object is already a context
-manager) and ``jax.sharding.get_abstract_mesh`` (reads the thread-resource
-physical mesh). Newer jax provides both natively and the shims no-op.
 """
 from __future__ import annotations
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as PS
-
-# --------------------------------------------------------------------------
-# jax forward-compat shims (0.4.x -> 0.5+ API surface used by the models).
-# --------------------------------------------------------------------------
-
-if not hasattr(jax, "set_mesh"):  # pragma: no cover - version-dependent
-    # Mesh is a context manager; `with jax.set_mesh(m):` == `with m:`.
-    jax.set_mesh = lambda mesh: mesh
-
-if not hasattr(jax.sharding, "get_abstract_mesh"):  # pragma: no cover
-    from jax.interpreters import pxla
-
-    def _get_abstract_mesh():
-        mesh = pxla.thread_resources.env.physical_mesh
-        return mesh if mesh.axis_names else None
-
-    jax.sharding.get_abstract_mesh = _get_abstract_mesh
-
-if not hasattr(jax, "shard_map"):  # pragma: no cover - version-dependent
-    from jax.experimental import shard_map as _shard_map_mod
-
-    def _shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-        if check_vma is not None:  # renamed from check_rep in newer jax
-            kw.setdefault("check_rep", check_vma)
-        return _shard_map_mod.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                        out_specs=out_specs, **kw)
-
-    jax.shard_map = _shard_map
-
 
 # --------------------------------------------------------------------------
 # Rules and overrides

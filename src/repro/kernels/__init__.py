@@ -1,4 +1,5 @@
-"""Pallas TPU kernels (validated on CPU via interpret=True) + XLA refs.
+"""Pallas TPU kernels + XLA refs. Every kernel takes ``interpret``
+without a default: True validates on CPU, False compiles with Mosaic.
 
     bitserial_matmul   the SIP array: packed-plane serial matmul (+dynamic)
     bitserial_conv     FUSED bit-serial convolution on an Ho-banded grid:

@@ -22,9 +22,9 @@ hold a whole feature map:
     sliding-window wiring — and assembles the band-local
     [rows_per_band*Wo, k*k*C] patch matrix directly in registers/VMEM.
   * The serial plane loop is UNROLLED IN THE KERNEL BODY: all Pw packed
-    plane tiles are staged per grid step, unpacked once, and each plane
-    issues one int8 MXU pass whose partial product is shift/negate-folded
-    into the int32 accumulator (2's-complement MSB negation — the
+    plane tiles are staged per grid step, each plane is unpacked in turn
+    and issues one int8 MXU pass whose partial product is shift/negate-
+    folded into the int32 accumulator (2's-complement MSB negation — the
     paper's negation block).
 
 VMEM accounting (see :func:`conv_vmem_bytes`, the single source of
@@ -47,15 +47,16 @@ a hot-path kwarg.
 `bitserial_conv_wgroup` is the STATIC per-filter-group precision variant
 (the paper's Sec 4.6 / DPRed): the serial weight-plane loop moves from
 the kernel body onto the grid — (B, n_bands, N/bn, Pw), plane innermost
-— and a scalar-prefetch count per group of ``bn`` output filters
-(computed ONCE at pack time from the OR-tree over the group's weights,
-carried by ``LayerPlan.w_group_counts``) gates it with
-``pl.when(p < count)``: whole (plane x filter-group) grid steps are
-skipped, with the (count-1)-th plane negated (2's-complement truncation
-at the group's effective width — value-preserving for OR-tree counts, so
+— and the pack-time count per group of ``w_group`` output filters
+(computed ONCE from the OR-tree over the group's weights, carried by
+``LayerPlan.w_group_counts``) gates it: ``pl.when`` skips whole
+(plane x N-tile) grid steps above the tile's largest count, and a
+per-filter plane-weight row zeroes the planes above each group's own
+count and negates its (count-1)-th plane (2's-complement truncation at
+the group's effective width — value-preserving for OR-tree counts, so
 the result is bit-identical to `bitserial_conv`). The band's patch
-matrix is assembled once per (band, filter-group) at plane 0 and reused
-from scratch across the plane steps.
+matrix is assembled once per band and reused from scratch across the
+N-tiles and plane steps.
 
 `bitserial_conv_dynamic` is the DYNAMIC-PRECISION transpose of the same
 design (Lascorz et al., the paper's runtime trimming): the serial axis
@@ -82,13 +83,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _unpack_planes(packed: jax.Array) -> jax.Array:
-    """uint8 [Pw, K8, bn] -> {0,1} int8 [Pw, K8*8, bn] (LE within byte)."""
-    pw, k8, bn = packed.shape
-    shifts = jnp.arange(8, dtype=jnp.uint8).reshape(1, 1, 8, 1)
-    bits = jnp.right_shift(packed[:, :, None, :], shifts) & jnp.uint8(1)
-    return bits.reshape(pw, k8 * 8, bn).astype(jnp.int8)
+from repro.kernels.bitserial_matmul import (column_plane_weights, live_plane,
+                                            tile_plane_counts, unpack_plane)
 
 
 def _patches(xv: jax.Array, kernel: int, stride: int, ho: int,
@@ -175,18 +171,19 @@ def _kernel(x_ref, wp_ref, out_ref, *, kernel: int, stride: int, w_bits: int,
     if kpad:                                        # match packed K rows
         patches = jnp.pad(patches, ((0, 0), (0, kpad)))
 
-    # One unpack for all Pw planes, then the unrolled serial plane loop:
-    # Pw int8 MXU passes, shift/negate folded into the int32 accumulate.
-    planes = _unpack_planes(wp_ref[...])            # [Pw, K8*8, bn] {0,1}
-    acc = jnp.zeros((patches.shape[0], planes.shape[-1]), jnp.int32)
+    # The unrolled serial plane loop: Pw int8 MXU passes, each plane
+    # unpacked from the staged tile, shift/negate folded into the int32
+    # accumulate.
+    bn = wp_ref.shape[-1]
+    acc = jnp.zeros((patches.shape[0], bn), jnp.int32)
     for p in range(w_bits):
         part = jax.lax.dot_general(
-            patches, planes[p],
+            patches, unpack_plane(wp_ref[p]),
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)       # int8 x {0,1} MXU pass
         sign = -1 if p == w_bits - 1 else 1         # MSB negation block
         acc += part * (sign * (1 << p))
-    out_ref[0, 0] = acc.reshape(rows, wo, planes.shape[-1])
+    out_ref[0, 0] = acc.reshape(rows, wo, bn)
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "stride", "w_bits",
@@ -195,7 +192,7 @@ def _kernel(x_ref, wp_ref, out_ref, *, kernel: int, stride: int, w_bits: int,
 def bitserial_conv(x: jax.Array, w_packed: jax.Array, *, kernel: int,
                    stride: int = 1, w_bits: int, bn: int = 128,
                    rows_per_band: int | None = None,
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool) -> jax.Array:
     """Fused bit-serial "same"-padded conv over packed weight planes.
 
     x: int8 [B, H, W, C]; w_packed: uint8 [Pw, ceil(k*k*C/8), N].
@@ -204,7 +201,7 @@ def bitserial_conv(x: jax.Array, w_packed: jax.Array, *, kernel: int,
     geometry, pad = k//2). ``rows_per_band`` tiles the grid over output
     rows (None = one band = the whole map); banding never changes the
     result — it only bounds the per-step VMEM footprint
-    (:func:`conv_vmem_bytes`). interpret=True validates on CPU.
+    (:func:`conv_vmem_bytes`). ``interpret=True`` validates on CPU.
     """
     assert kernel % 2 == 1, f"odd kernels only, got {kernel}"
     b, h, w, c = x.shape
@@ -240,26 +237,28 @@ def bitserial_conv(x: jax.Array, w_packed: jax.Array, *, kernel: int,
     return out.reshape(b, nb * rpb, wo, n)[:, :ho]
 
 
-def _kernel_wg(counts_ref, x_ref, wp_ref, out_ref, patch_ref, acc_ref, *,
-               kernel: int, stride: int, w_bits: int, rows: int, wo: int,
-               kpad: int):
+def _kernel_wg(counts_ref, x_ref, wp_ref, pw_ref, out_ref, patch_ref,
+               acc_ref, *, kernel: int, stride: int, w_bits: int, rows: int,
+               wo: int, kpad: int):
     """Grid = (B, n_bands, N/bn, Pw): serial WEIGHT-plane axis innermost.
 
-    counts_ref (scalar prefetch) holds the pack-time effective weight
-    precision per filter group (= per N-tile of ``bn`` columns — the
-    paper's Sec 4.6 per-group metadata). Plane grid steps with
-    p >= count are skipped entirely via pl.when — no patch matmul, and
-    on TPU no HBM fetch of that plane's tile — with the (count-1)-th
-    plane negated (2's complement at the group's effective width). The
-    band's patch rows are assembled once at plane 0 (counts have a 1-bit
-    floor, so plane 0 always executes) and reused from scratch."""
+    counts_ref (scalar prefetch) holds the largest pack-time effective
+    weight precision among each N-tile's filter groups (the paper's Sec
+    4.6 per-group metadata). Plane grid steps with p >= that count are
+    skipped entirely via pl.when — no patch matmul, and no HBM fetch of
+    that plane's tile. pw_ref holds this plane's signed weight per
+    output filter (``weightgroups.group_plane_weights`` spread over the
+    filters), which applies each group's own count inside the tile: 0
+    above it, the (count-1)-th plane negated (2's complement at the
+    group's effective width). The band's patch rows are assembled once
+    and reused from scratch (counts have a 1-bit floor, so plane 0
+    always executes)."""
     l = pl.program_id(2)
     p = pl.program_id(3)
 
     # The band's patch matrix depends only on (batch, band): assemble it
-    # once at the FIRST filter group and reuse the scratch across all
-    # N/bn groups — at bn = w_group (16) a per-group prologue would redo
-    # the implicit im2col N/16 times per band.
+    # once at the FIRST N-tile and reuse the scratch across all N/bn
+    # tiles.
     @pl.when((l == 0) & (p == 0))
     def _patches_init():
         patches = _patches(x_ref[0, 0], kernel, stride, rows, wo)
@@ -271,17 +270,13 @@ def _kernel_wg(counts_ref, x_ref, wp_ref, out_ref, patch_ref, acc_ref, *,
     def _acc_init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    count = counts_ref[l]
-
-    @pl.when(p < count)
+    @pl.when(p < counts_ref[l])
     def _work():
-        plane = _unpack_planes(wp_ref[...])[0]      # [K8*8, bn] {0,1} int8
         part = jax.lax.dot_general(
-            patch_ref[...], plane,
+            patch_ref[...], unpack_plane(wp_ref[0]),
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)       # int8 x {0,1} MXU pass
-        sign = jnp.where(p == count - 1, -1, 1)     # MSB at effective width
-        acc_ref[...] += part * (sign * (jnp.int32(1) << p))
+        acc_ref[...] += part * pw_ref[0]
 
     @pl.when(p == w_bits - 1)
     def _done():
@@ -289,24 +284,25 @@ def _kernel_wg(counts_ref, x_ref, wp_ref, out_ref, patch_ref, acc_ref, *,
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "stride", "w_bits",
-                                             "bn", "rows_per_band",
-                                             "interpret"))
+                                             "w_group", "bn",
+                                             "rows_per_band", "interpret"))
 def bitserial_conv_wgroup(x: jax.Array, w_packed: jax.Array,
                           counts: jax.Array, *, kernel: int, stride: int = 1,
-                          w_bits: int, bn: int = 16,
+                          w_bits: int, w_group: int = 16, bn: int = 128,
                           rows_per_band: int | None = None,
-                          interpret: bool = True) -> jax.Array:
+                          interpret: bool) -> jax.Array:
     """Fused bit-serial conv with STATIC per-filter-group plane skipping.
 
     x: int8 [B, H, W, C]; w_packed: uint8 [Pw, ceil(k*k*C/8), N]; counts:
-    int32 [N/bn] — the pack-time OR-tree effective weight precision of
-    each group of ``bn`` output filters (``LayerPlan.w_group_counts``;
-    callers pad N to a multiple of ``bn`` — zero columns fit any count).
-    Filter group l executes only counts[l] of the ``w_bits`` serial
-    weight planes. Returns int32 [B, Ho, Wo, N] ("same" geometry),
-    bit-identical to :func:`bitserial_conv` whenever every group's
-    weights fit in its count (the OR-tree guarantee); for arbitrary
-    counts it matches the truncating oracle
+    int32 [ceil(N/w_group)] — the pack-time OR-tree effective weight
+    precision of each group of ``w_group`` output filters
+    (``LayerPlan.w_group_counts``). Filter group g executes only
+    counts[g] of the ``w_bits`` serial weight planes; ``bn`` is the
+    N-tile, which may hold several groups (on the TPU it must be a
+    multiple of 128 or all of N). Returns int32 [B, Ho, Wo, N] ("same"
+    geometry), bit-identical to :func:`bitserial_conv` whenever every
+    group's weights fit in its count (the OR-tree guarantee); for
+    arbitrary counts it matches the truncating oracle
     ``ref.bitserial_conv_wgroup_ref``. ``rows_per_band`` bands the grid
     over output rows exactly as in the static kernel.
     """
@@ -317,7 +313,9 @@ def bitserial_conv_wgroup(x: jax.Array, w_packed: jax.Array,
     assert pw == w_bits and k8 == -(-kkc // 8), (w_packed.shape, kkc, w_bits)
     bn = min(bn, n)
     assert n % bn == 0, (n, bn)
-    assert counts.shape == (n // bn,), (counts.shape, n, bn)
+    assert counts.shape == (-(-n // w_group),), (counts.shape, n, w_group)
+    tiles = tile_plane_counts(counts, w_group, n, bn)
+    weights = column_plane_weights(counts, w_group, n, w_bits)
 
     pad = kernel // 2
     xp = jnp.pad(x, ((0, 0), (pad, pad), (pad, pad), (0, 0)))
@@ -333,7 +331,9 @@ def bitserial_conv_wgroup(x: jax.Array, w_packed: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, band_rows, wp_, c),
                          lambda i, j, l, p, counts: (i, j, 0, 0, 0)),
-            pl.BlockSpec((1, k8, bn), lambda i, j, l, p, counts: (p, 0, l)),
+            pl.BlockSpec((1, k8, bn), lambda i, j, l, p, counts:
+                         (live_plane(p, counts[l]), 0, l)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, l, p, counts: (p, 0, l)),
         ],
         out_specs=pl.BlockSpec((1, 1, rpb, wo, bn),
                                lambda i, j, l, p, counts: (i, j, 0, 0, l)),
@@ -347,13 +347,13 @@ def bitserial_conv_wgroup(x: jax.Array, w_packed: jax.Array,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((b, nb, rpb, wo, n), jnp.int32),
         interpret=interpret,
-    )(counts.astype(jnp.int32), xb, w_packed)
+    )(tiles, xb, w_packed, weights)
     return out.reshape(b, nb * rpb, wo, n)[:, :ho]
 
 
-def _kernel_dyn(counts_ref, x_ref, w_ref, out_ref, rows_ref, acc_ref, *,
-                kernel: int, stride: int, a_bits: int, rows: int, wo: int,
-                gsz: int, kpad: int):
+def _kernel_dyn(counts_ref, x_ref, w_ref, out_ref, band_ref, rows_ref,
+                acc_ref, *, kernel: int, stride: int, a_bits: int, rows: int,
+                wo: int, gsz: int, kpad: int):
     """Grid = (B, G, Pa): the serial ACTIVATION-plane axis innermost.
 
     The dynamic-precision transpose of the static kernel: weights ride as
@@ -367,7 +367,9 @@ def _kernel_dyn(counts_ref, x_ref, w_ref, out_ref, rows_ref, acc_ref, *,
     1-bit floor), from the group's OWN input row band: the band covers
     the ``rows`` output rows group g's windows span, so the prologue
     builds rows*Wo >= gsz patch rows and slices the group's gsz at its
-    in-band column offset — band-local work, independent of Ho*Wo."""
+    in-band column offset — band-local work, independent of Ho*Wo. Both
+    patch scratches are int32: Mosaic reads a dynamic row offset from a
+    32-bit ref only."""
     b = pl.program_id(0)
     g = pl.program_id(1)
     p = pl.program_id(2)
@@ -377,17 +379,17 @@ def _kernel_dyn(counts_ref, x_ref, w_ref, out_ref, rows_ref, acc_ref, *,
         patches = _patches(x_ref[0, 0], kernel, stride, rows, wo)
         if kpad:
             patches = jnp.pad(patches, ((0, 0), (0, kpad)))
+        band_ref[...] = patches.astype(jnp.int32)
         w0 = g * gsz                        # first window of the group
         off = w0 - (w0 // wo) * wo          # its column offset in the band
-        rows_ref[...] = jax.lax.dynamic_slice(
-            patches, (off, 0), (gsz, patches.shape[1]))
+        rows_ref[...] = band_ref[pl.ds(off, gsz), :]
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     count = counts_ref[b, g]
 
     @pl.when(p < count)
     def _work():
-        bit = ((rows_ref[...].astype(jnp.int32) >> p) & 1).astype(jnp.int8)
+        bit = ((rows_ref[...] >> p) & 1).astype(jnp.int8)
         part = jax.lax.dot_general(
             bit, w_ref[...],
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -405,7 +407,7 @@ def _kernel_dyn(counts_ref, x_ref, w_ref, out_ref, rows_ref, acc_ref, *,
 def bitserial_conv_dynamic(x: jax.Array, wq: jax.Array, counts: jax.Array, *,
                            kernel: int, stride: int = 1, a_bits: int,
                            group_size: int = 256,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """Fused "same"-padded conv with runtime activation-plane trimming.
 
     x: int8 [B, H, W, C]; wq: int8 [K8, N] — the UNPACKED weights (or one
@@ -451,7 +453,8 @@ def bitserial_conv_dynamic(x: jax.Array, wq: jax.Array, counts: jax.Array, *,
             pl.BlockSpec((k8, n), lambda i, j, p, counts: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, gsz, n), lambda i, j, p, counts: (i, j, 0)),
-        scratch_shapes=[pltpu.VMEM((gsz, k8), jnp.int8),    # group patch rows
+        scratch_shapes=[pltpu.VMEM((rows_pg * wo, k8), jnp.int32),  # band
+                        pltpu.VMEM((gsz, k8), jnp.int32),   # group patch rows
                         pltpu.VMEM((gsz, n), jnp.int32)],   # accumulator
     )
     out = pl.pallas_call(

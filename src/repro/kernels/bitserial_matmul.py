@@ -12,16 +12,17 @@ This is the SIP array adapted to the TPU memory hierarchy:
   * The serial plane loop is the innermost grid dimension; partial products
     are shifted by 2^p and accumulated in the output tile, with the MSB
     plane negated (2's complement — the paper's negation block).
-  * Dynamic precision reduction: an optional scalar-prefetch plane-count
-    lets the kernel skip planes above the runtime effective precision
-    (Lascorz et al.) — blocks with plane >= count are masked via pl.when
-    so no MXU work (and on TPU no HBM fetch of that plane's tile) happens.
-    The SAME kernel doubles as the STATIC per-filter-group weight
-    trimming path (paper Sec 4.6): when the packed operand is the
-    weights, the backend feeds the pack-time OR-tree counts from
-    ``LayerPlan.w_group_counts`` with bn = the filter-group size —
-    per-group weight precisions are known at pack time, so no runtime
-    detection is needed and the counts are plan constants.
+  * Dynamic precision reduction: scalar-prefetch plane counts let the
+    kernel skip planes above the runtime effective precision (Lascorz et
+    al.) — blocks with plane >= the tile's count are masked via pl.when
+    and re-name the last fetched plane, so neither MXU work nor an HBM
+    fetch happens for them. A per-column plane-weight row carries each
+    column group's own count inside the tile. The SAME kernel doubles as
+    the STATIC per-filter-group weight trimming path (paper Sec 4.6):
+    when the packed operand is the weights, the backend feeds the
+    pack-time OR-tree counts from ``LayerPlan.w_group_counts`` over
+    groups of 16 filters on 128-wide N-tiles — per-group weight
+    precisions are known at pack time, so the counts are plan constants.
 
 Activations are int8 (Pa <= 8 after quantization). This realizes the
 paper's FCL law (work, bytes ∝ Pw) and, combined with 4-bit activation
@@ -37,13 +38,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import weightgroups as wg
 
-def _unpack_plane(packed_tile: jax.Array) -> jax.Array:
-    """uint8 [bk8, bn] -> {0,1} int8 [bk8*8, bn] (little-endian in byte)."""
+
+def unpack_plane(packed_tile: jax.Array) -> jax.Array:
+    """uint8 [bk8, bn] -> {0,1} int8 [bk8*8, bn] (little-endian in byte).
+
+    Row 8j+i of the plane is bit i of packed row j. The unpack runs in
+    int32, whose (8, 128) register tile makes the [bk8, 8, bn] ->
+    [bk8*8, bn] merge free: each packed row is broadcast across one
+    tile's 8 sublanes and shifted right by its sublane index. Mosaic
+    lowers neither 8-bit shifts nor that merge for 8-bit vectors."""
     bk8, bn = packed_tile.shape
-    shifts = jnp.arange(8, dtype=jnp.uint8).reshape(1, 8, 1)
-    bits = jnp.right_shift(packed_tile[:, None, :], shifts) & jnp.uint8(1)
-    return bits.reshape(bk8 * 8, bn).astype(jnp.int8)
+    rows = jnp.broadcast_to(packed_tile.astype(jnp.int32)[:, None, :],
+                            (bk8, 8, bn))
+    shift = jax.lax.broadcasted_iota(jnp.int32, (bk8, 8, bn), 1)
+    return ((rows >> shift) & 1).reshape(bk8 * 8, bn).astype(jnp.int8)
 
 
 def _kernel(x_ref, wp_ref, out_ref, acc_ref, *, w_bits: int, nk: int):
@@ -55,7 +65,7 @@ def _kernel(x_ref, wp_ref, out_ref, acc_ref, *, w_bits: int, nk: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    plane = _unpack_plane(wp_ref[0])                     # [bk, bn] {0,1}
+    plane = unpack_plane(wp_ref[0])                      # [bk, bn] {0,1}
     part = jax.lax.dot_general(
         x_ref[...], plane,
         dimension_numbers=(((1,), (0,)), ((), ())),
@@ -71,11 +81,12 @@ def _kernel(x_ref, wp_ref, out_ref, acc_ref, *, w_bits: int, nk: int):
 @functools.partial(jax.jit, static_argnames=("w_bits", "bm", "bn", "bk", "interpret"))
 def bitserial_matmul(x: jax.Array, w_packed: jax.Array, *, w_bits: int,
                      bm: int = 128, bn: int = 128, bk: int = 512,
-                     interpret: bool = True) -> jax.Array:
+                     interpret: bool) -> jax.Array:
     """x: int8 [M, K]; w_packed: uint8 [Pw, K//8, N] -> int32 [M, N].
 
     Integer-exact: result == x.astype(i32) @ unpack(w_packed).astype(i32).
-    interpret=True executes on CPU (validation); on TPU pass False.
+    ``interpret=True`` runs the kernel on CPU (validation); Mosaic
+    compiles it for the TPU with ``interpret=False``.
     """
     m, k = x.shape
     pw, k8, n = w_packed.shape
@@ -99,11 +110,14 @@ def bitserial_matmul(x: jax.Array, w_packed: jax.Array, *, w_bits: int,
     )(x, w_packed)
 
 
-def _kernel_dyn(counts_ref, x_ref, wp_ref, out_ref, acc_ref, *,
+def _kernel_dyn(counts_ref, x_ref, wp_ref, pw_ref, out_ref, acc_ref, *,
                 w_bits: int, nk: int):
-    """Dynamic-precision variant: counts_ref (scalar prefetch) holds the
-    runtime effective weight precision per N-tile (per-group metadata of the
-    paper Sec 4.6); planes >= count are skipped entirely."""
+    """Counted variant: counts_ref (scalar prefetch) holds the largest
+    plane count of each N-tile, and planes at or above it are skipped
+    entirely. pw_ref holds this plane's signed weight per column
+    (``weightgroups.group_plane_weights`` spread over the columns): +2^p
+    below a column group's count, -2^(count-1) at it, 0 above, so groups
+    of any width share one 128-aligned tile."""
     j = pl.program_id(1)
     kk = pl.program_id(2)
     p = pl.program_id(3)
@@ -112,38 +126,69 @@ def _kernel_dyn(counts_ref, x_ref, wp_ref, out_ref, acc_ref, *,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    count = counts_ref[j]
-
-    @pl.when(p < count)
+    @pl.when(p < counts_ref[j])
     def _work():
-        plane = _unpack_plane(wp_ref[0])
+        plane = unpack_plane(wp_ref[0])
         part = jax.lax.dot_general(
             x_ref[...], plane,
             dimension_numbers=(((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32)
-        sign = jnp.where(p == count - 1, -1, 1)
-        acc_ref[...] += part * (sign * (1 << p))
+        acc_ref[...] += part * pw_ref[0]
 
     @pl.when((kk == nk - 1) & (p == w_bits - 1))
     def _done():
         out_ref[...] = acc_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("w_bits", "bm", "bn", "bk", "interpret"))
+def column_plane_weights(counts: jax.Array, group: int, n: int,
+                         bits: int) -> jax.Array:
+    """int32 [bits, 1, n]: the signed weight of each plane per column,
+    for per-group plane ``counts`` over groups of ``group`` columns."""
+    table = wg.group_plane_weights(counts, bits)         # [groups, bits]
+    return jnp.repeat(table.T, group, axis=1)[:, None, :n]
+
+
+def tile_plane_counts(counts: jax.Array, group: int, n: int,
+                      bn: int) -> jax.Array:
+    """int32 [n/bn]: the largest plane count among each N-tile's columns
+    (a wider two's-complement width keeps every narrower group's value,
+    and the per-column weights zero the planes a group does not use)."""
+    cols = jnp.repeat(jnp.asarray(counts, jnp.int32), group)[:n]
+    return cols.reshape(n // bn, bn).max(axis=1)
+
+
+def live_plane(p, count):
+    """Plane block index for grid step ``p`` of a tile that executes
+    ``count`` planes: skipped planes re-name the last executed one, so
+    the pipeline fetches nothing new from HBM for them."""
+    return jnp.maximum(jnp.minimum(p, count - 1), 0)
+
+
+@functools.partial(jax.jit, static_argnames=("w_bits", "group", "bm", "bn",
+                                             "bk", "interpret"))
 def bitserial_matmul_dynamic(x: jax.Array, w_packed: jax.Array,
                              plane_counts: jax.Array, *, w_bits: int,
+                             group: int | None = None,
                              bm: int = 128, bn: int = 128, bk: int = 512,
-                             interpret: bool = True) -> jax.Array:
-    """Like bitserial_matmul but executes only plane_counts[j] planes for
-    N-tile j. Weights must be stored group-quantized so that tile j's values
-    fit in plane_counts[j] bits (2's complement within that width)."""
+                             interpret: bool) -> jax.Array:
+    """Like bitserial_matmul but column group g executes only
+    plane_counts[g] planes, the (count-1)-th negated (two's complement
+    at the effective width). A group is ``group`` columns (default: one
+    N-tile of ``bn``); N-tiles hold whole or partial groups alike, so
+    ``bn`` stays 128-aligned however narrow the groups are. Values that
+    fit their group's count give the untrimmed result; for arbitrary
+    counts this is the truncating oracle
+    ``ref.bitserial_matmul_wgroup_ref``."""
     m, k = x.shape
     pw, k8, n = w_packed.shape
     assert pw == w_bits and k8 * 8 == k
     bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0 and bk % 8 == 0
+    group = bn if group is None else group
+    assert plane_counts.shape == (-(-n // group),), (plane_counts.shape, n)
     nk = k // bk
-    assert plane_counts.shape == (n // bn,)
+    tiles = tile_plane_counts(plane_counts, group, n, bn)
+    weights = column_plane_weights(plane_counts, group, n, w_bits)
 
     grid = (m // bm, n // bn, nk, w_bits)
     gs = pltpu.PrefetchScalarGridSpec(
@@ -151,7 +196,9 @@ def bitserial_matmul_dynamic(x: jax.Array, w_packed: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk, p, counts: (i, kk)),
-            pl.BlockSpec((1, bk // 8, bn), lambda i, j, kk, p, counts: (p, kk, j)),
+            pl.BlockSpec((1, bk // 8, bn), lambda i, j, kk, p, counts:
+                         (live_plane(p, counts[j]), kk, j)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, kk, p, counts: (p, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk, p, counts: (i, j)),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
@@ -161,4 +208,4 @@ def bitserial_matmul_dynamic(x: jax.Array, w_packed: jax.Array,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
-    )(plane_counts, x, w_packed)
+    )(tiles, x, w_packed, weights)

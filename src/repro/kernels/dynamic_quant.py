@@ -39,7 +39,7 @@ def _kernel(x_ref, xq_ref, scale_ref, eff_ref, *, group_size: int, bits: int):
 
 @functools.partial(jax.jit, static_argnames=("group_size", "bits", "bm", "interpret"))
 def dynamic_quant(x: jax.Array, *, group_size: int = 256, bits: int = 8,
-                  bm: int = 256, interpret: bool = True):
+                  bm: int = 256, interpret: bool):
     """x: f32 [M, K] -> (xq int8 [M,K], scale f32 [M,G], eff_bits i32 [M,G]).
 
     G = K // group_size. Matches ref.dynamic_quant_ref exactly.

@@ -74,7 +74,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = True, window: int | None = None,
                     scale: float | None = None,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q,k,v: [B, H, S, D] (same head count — repeat KV upstream for GQA).
 
     Returns [B, H, S, D]. Sliding window = keys in (q - window, q].

@@ -241,10 +241,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, weights: str = "dense",
                      "temp_size_in_bytes", "generated_code_size_in_bytes",
                      "alias_size_in_bytes"):
             mem_d[attr] = getattr(mem, attr, None)
-        cost = compiled.cost_analysis() or {}
-        if isinstance(cost, (list, tuple)):  # jax<=0.4.x: [dict] per device
-            cost = cost[0] if cost else {}
-        cost = dict(cost)
+        cost = dict(compiled.cost_analysis() or {})
         hlo = compiled.as_text()
         totals = hloanalysis.analyze_hlo(hlo)
         profile = hloanalysis.attribute(hlo) if profile_ops else None

@@ -4,6 +4,15 @@ XLA flag before any jax initialization)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes):
+    """``jax.make_mesh`` with ``Auto`` axes: sharding stays a compiler
+    decision that the models steer with ``dist.sharding.constraint``
+    (jax's default ``Explicit`` axes would make every op's operand
+    shardings part of its type)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -12,11 +21,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     parallelism (gradient reduction only — the slow DCN hop)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n_devices: int | None = None, model: int = 1):
     """Small mesh over locally visible devices (tests / examples)."""
     n = n_devices or len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))

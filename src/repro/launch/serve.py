@@ -138,7 +138,8 @@ def _server_prompt(cfg, args, j: int):
 def _serve_server(cfg, args, policy):
     """Continuous-batching server mode: ``--server N`` staggered requests
     through a BatchingEngine (supervised when ``--guarded``); returns the
-    per-request streams stacked [N, gen_len] for the CI stream diff.
+    streams of the requests that finished, stacked [n_done, gen_len] for
+    the CI stream diff, and the number that did not finish.
 
     Lifecycle wiring: SIGINT/SIGTERM flips a stop flag checked at every
     step boundary; the engine then runs ``shutdown(--drain-timeout)`` —
@@ -149,7 +150,7 @@ def _serve_server(cfg, args, policy):
 
     import numpy as np
     from repro.api import session as loom
-    from repro.runtime.batching import BatchingEngine
+    from repro.runtime.batching import BatchingEngine, streams as streamlib
 
     sess = loom.compile(cfg, policy, mode=args.mode, backend=args.backend,
                         rng=0, guarded=args.guarded)
@@ -159,6 +160,7 @@ def _serve_server(cfg, args, policy):
         from repro.runtime import ServingSupervisor
         target = sup = ServingSupervisor(sess)
     eng = BatchingEngine(target, max_batch=args.batch,
+                         max_seq=args.max_seq,
                          max_queue=args.max_queue,
                          step_timeout_s=args.step_timeout,
                          audit_rate=args.audit_rate,
@@ -191,11 +193,13 @@ def _serve_server(cfg, args, policy):
             signal.signal(s, h)
         if sup is not None:
             sup.close()
-    streams = np.stack([np.asarray(h.tokens_so_far()) for h in handles
-                        if len(h.tokens_so_far()) == args.gen_len]) \
-        if handles else np.zeros((0, args.gen_len), np.int32)
+    done = [h for h in handles if h.state == streamlib.DONE]
+    n_unfinished = args.server - len(done)
+    streams = np.stack([np.asarray(h.tokens_so_far()) for h in done]) \
+        if done else np.zeros((0, args.gen_len), np.int32)
     st = eng.stats
-    print(f"[serve] server: {args.server} requests done "
+    print(f"[serve] server: {len(done)}/{args.server} requests done "
+          f"unfinished={n_unfinished} "
           f"state={eng.health()['state']} "
           f"engine={eng.state} drained={summary['drained']} "
           f"occupancy={st.batch_occupancy:.2f} "
@@ -213,7 +217,7 @@ def _serve_server(cfg, args, policy):
           f"integrity_checks={st.n_integrity_checks} "
           f"quarantines={st.n_quarantines} "
           f"audit_lag_p95={st.p95_audit_lag_s:.3f}s")
-    return streams
+    return streams, n_unfinished
 
 
 def _cnn_inputs(cfg, args):
@@ -261,6 +265,13 @@ def _classify_session(cfg, args, policy):
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the architecture's published config "
+                         "(default: its smoke preset)")
+    ap.add_argument("--max-seq", type=int, default=None, metavar="N",
+                    help="KV cache length per sequence (default: the "
+                         "config's max_seq); a published config's 32k "
+                         "cache does not fit one chip at batch > 1")
     ap.add_argument("--mode", default="serve_int8",
                     choices=["dense", "serve_int8", "serve_packed"])
     ap.add_argument("--api", default="session", choices=["session", "plan"],
@@ -331,15 +342,20 @@ def main(argv=None):
                          "(CI diffs session vs plan runs)")
     args = ap.parse_args(argv)
 
+    import dataclasses
+
     import numpy as np
     from repro.core.policy import uniform_policy
+    from repro.launch import compile_cache
 
-    cfg = configs.get(args.arch, smoke=True)
+    compile_cache.enable()
+    cfg = configs.get(args.arch, smoke=not args.full)
+    if args.max_seq is not None and hasattr(cfg, "max_seq"):
+        cfg = dataclasses.replace(cfg, max_seq=args.max_seq)
     policy = uniform_policy(args.a_bits, args.w_bits,
                             dynamic_a=args.dynamic_a)
     if args.dynamic_a:
-        import dataclasses as dc
-        policy = dc.replace(policy, group_size=args.group_size)
+        policy = dataclasses.replace(policy, group_size=args.group_size)
     if hasattr(cfg, "convs"):            # CNN classification cell
         if args.server:
             raise SystemExit("--server is an LM decode mode; CNN configs "
@@ -350,7 +366,7 @@ def main(argv=None):
               f"({args.backend}{', dynamic-a' if args.dynamic_a else ''}); "
               f"predictions: {gen}")
     elif args.server:
-        gen = _serve_server(cfg, args, policy)
+        gen, n_unfinished = _serve_server(cfg, args, policy)
         print(f"[serve] generated {gen.shape} tokens via batching engine "
               f"({args.backend}{', dynamic-a' if args.dynamic_a else ''})")
     else:
@@ -362,6 +378,9 @@ def main(argv=None):
     if args.out_tokens:
         np.save(args.out_tokens, gen)
         print(f"[serve] saved outputs to {args.out_tokens}")
+    if args.server and n_unfinished:
+        raise SystemExit(f"[serve] {n_unfinished} of {args.server} requests "
+                         f"did not finish")
     print("done")
 
 

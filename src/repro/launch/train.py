@@ -123,7 +123,10 @@ def main(argv=None):
 
     from repro.core.policy import uniform_policy
     from repro.data import DataConfig, make_iterator
+    from repro.launch import compile_cache
     from repro.launch.mesh import make_host_mesh
+
+    compile_cache.enable()
 
     cfg = configs.get(args.arch, smoke=args.smoke)
     exec_cfg = planlib.build_plan(

@@ -39,6 +39,8 @@ is built by the same constructors.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as PS
@@ -258,10 +260,17 @@ def _convert_linear_int8(p, prec):
     return {"wq": wq.astype(jnp.int8), "w_scale": w_scale.astype(jnp.float32)}
 
 
+@functools.partial(jax.jit, static_argnums=1)
+def _quantize_pack(w, bits: int):
+    # One program per weight shape: run op by op, the bit-plane stack of
+    # a 2048 x 151936 head alone would take 10 GB of int32 temporaries.
+    wq, w_scale = q.quantize(w.astype(jnp.float32), bits)
+    return bitpack.pack_weights(wq, bits), w_scale.astype(jnp.float32)
+
+
 def _convert_linear_packed(p, prec):
-    wq, w_scale = q.quantize(p["w"].astype(jnp.float32), prec.w_bits)
-    return {"w_packed": bitpack.pack_weights(wq, prec.w_bits),
-            "w_scale": w_scale.astype(jnp.float32)}
+    w_packed, w_scale = _quantize_pack(p["w"], prec.w_bits)
+    return {"w_packed": w_packed, "w_scale": w_scale}
 
 
 _LINEAR_CONVERTERS = {"serve_int8": _convert_linear_int8,

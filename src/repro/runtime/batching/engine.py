@@ -760,7 +760,7 @@ class BatchingEngine:
         plan = self.session.plan
         if not getattr(plan.policy, "w_group", 0):
             return
-        from repro.core import bitpack, weightgroups
+        from repro.core import weightgroups
         named = {"lm_head": converted.get("head", {})} \
             if isinstance(converted, dict) else {}
         for (name, kind), lp in plan.layers.items():
@@ -770,10 +770,9 @@ class BatchingEngine:
             wp = p.get("w_packed") if isinstance(p, dict) else None
             if wp is None or getattr(wp, "ndim", 0) != 3:
                 continue
-            w_bits = wp.shape[0]
-            wq = bitpack.unpack_weights(wp, w_bits)
             counts = tuple(int(v) for v in np.asarray(
-                weightgroups.weight_group_counts(wq, w_bits, lp.w_group)))
+                weightgroups.packed_group_counts(wp, wp.shape[0],
+                                                 lp.w_group)))
             if counts != lp.w_group_counts:
                 raise guards.ReloadMismatchError(
                     f"layer {name!r} ({kind}): packed weight-group counts "

@@ -46,6 +46,7 @@ import warnings
 import numpy as np
 
 from repro.api import guards
+from repro.api.backend import default_fallback_chain
 from repro.runtime import faults
 from repro.runtime.supervisor import StepMonitor, TransientWorkerError
 
@@ -131,18 +132,22 @@ class ServingSupervisor:
     ``timeout_s``: per-request wall-clock budget (None = unbounded).
     ``rebuild``: optional ``rebuild(backend_name) -> ServingSession`` hook
     enabling whole-session degradation on permanent faults, walked down
-    ``fallback_backends``. ``check_numerics``: verify every concrete
+    ``fallback_backends`` (default: the backend layer's
+    ``default_fallback_chain()`` after ``pallas_tpu``, which holds no
+    interpreter on a TPU). ``check_numerics``: verify every concrete
     output is finite (bit-transparent — values are never modified).
     """
 
     def __init__(self, session, *, max_retries: int = 2,
                  backoff_s: float = 0.02, timeout_s: float | None = None,
-                 rebuild=None, fallback_backends=("pallas_interpret", "xla"),
+                 rebuild=None, fallback_backends=None,
                  check_numerics: bool = True):
         self.max_retries = max_retries
         self.backoff_s = backoff_s
         self.timeout_s = timeout_s
         self.rebuild = rebuild
+        if fallback_backends is None:
+            fallback_backends = default_fallback_chain()[1:]
         self.fallback_backends = list(fallback_backends)
         self.check_numerics = check_numerics
         self.state = HEALTHY

@@ -27,6 +27,7 @@ from repro.api import plan as planlib
 from repro.core import bitpack, dynamic, quantize as q
 from repro.core.policy import LayerPrecision, PrecisionPolicy, uniform_policy
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 from repro.models import cnn, layers as L
 
 jax.config.update("jax_platform_name", "cpu")
@@ -277,7 +278,7 @@ def test_compile_with_mesh_shardings():
     """The mesh wiring of loom.compile (and, via delegation, the launch
     layer's jit_serve_steps) must serve identically to the plain path."""
     cfg = configs.get("qwen3-1.7b", smoke=True)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     policy = uniform_policy(8, 8)
     toks = jnp.asarray(np.random.default_rng(0).integers(
         1, cfg.vocab, size=(2, 8)), jnp.int32)

@@ -4,6 +4,8 @@ import os
 import subprocess
 import sys
 
+import pytest
+
 ENV = {"PYTHONPATH": "src", "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
        "HOME": os.environ.get("HOME", "/root")}
 
@@ -44,3 +46,29 @@ def test_serve_cli_packed():
               "--gen-len", "3"])
     assert r.returncode == 0, r.stderr[-2000:]
     assert "done" in r.stdout
+
+
+@pytest.mark.parametrize("max_seq,unfinished", [("16", 0), ("13", 1)])
+def test_serve_server_exit_code_counts_unfinished(tmp_path, max_seq,
+                                                  unfinished):
+    """Server mode exits non-zero when a request did not finish. Request j
+    has prompt 8+j and 4 new tokens, so a 13-token cache fails the third
+    request (10 + 4 > 13) and serves the other two. The run keeps its
+    compilation cache where JAX_COMPILATION_CACHE_DIR says."""
+    env = dict(ENV, JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    r = subprocess.run(
+        [sys.executable, "-m", "repro.launch.serve", "--arch", "qwen3-1.7b",
+         "--mode", "serve_packed", "--server", "3", "--batch", "2",
+         "--prompt-len", "8", "--gen-len", "4", "--max-seq", max_seq],
+        capture_output=True, text=True, cwd=".", env=env, timeout=560)
+    assert f"{3 - unfinished}/3 requests done unfinished={unfinished}" \
+        in r.stdout, r.stdout[-2000:] + r.stderr[-2000:]
+    if unfinished:
+        assert r.returncode != 0
+        assert "did not finish" in r.stderr
+        assert "done\n" not in r.stdout
+    else:
+        assert r.returncode == 0, r.stderr[-2000:]
+        assert r.stdout.endswith("done\n")
+    assert any(tmp_path.iterdir()), "no compilation cache entries written"

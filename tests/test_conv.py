@@ -58,7 +58,7 @@ def test_fused_conv_exact_both_paths(kernel, stride, pa, pw):
     x, wq, wp, oracle = _conv_case(kernel, stride, pa, pw,
                                    seed=kernel * 100 + stride * 10 + pw)
     y_pal = bitserial_conv(x, wp, kernel=kernel, stride=stride, w_bits=pw,
-                           bn=8)
+                           bn=8, interpret=True)
     np.testing.assert_array_equal(np.asarray(y_pal), np.asarray(oracle))
     y_xla = ref.bitserial_conv_ref(x, wp, kernel=kernel, stride=stride,
                                    w_bits=pw)
@@ -70,13 +70,13 @@ def test_fused_conv_exact_both_paths(kernel, stride, pa, pw):
 def test_fused_conv_shapes_and_tiles(h, c, n, bn):
     """Odd maps, K%8 padding rows, and N-tiling all stay exact."""
     x, wq, wp, oracle = _conv_case(3, 2, 8, 8, b=3, h=h, c=c, n=n, seed=h + n)
-    y = bitserial_conv(x, wp, kernel=3, stride=2, w_bits=8, bn=bn)
+    y = bitserial_conv(x, wp, kernel=3, stride=2, w_bits=8, bn=bn, interpret=True)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(oracle))
 
 
 def test_fused_conv_batch_one_and_wide():
     x, wq, wp, oracle = _conv_case(5, 1, 8, 8, b=1, h=12, c=4, n=32, seed=9)
-    y = bitserial_conv(x, wp, kernel=5, stride=1, w_bits=8, bn=32)
+    y = bitserial_conv(x, wp, kernel=5, stride=1, w_bits=8, bn=32, interpret=True)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(oracle))
 
 

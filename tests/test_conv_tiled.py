@@ -55,11 +55,11 @@ def test_banded_static_exact_grid(kernel, stride, pa, pw):
     oracle = ref.bitserial_conv_ref(x, wp, kernel=kernel, stride=stride,
                                     w_bits=pw)
     y_untiled = bitserial_conv(x, wp, kernel=kernel, stride=stride,
-                               w_bits=pw, bn=8)
+                               w_bits=pw, bn=8, interpret=True)
     np.testing.assert_array_equal(np.asarray(y_untiled), np.asarray(oracle))
     for rpb in (1, 4):
         y_band = bitserial_conv(x, wp, kernel=kernel, stride=stride,
-                                w_bits=pw, bn=8, rows_per_band=rpb)
+                                w_bits=pw, bn=8, rows_per_band=rpb, interpret=True)
         np.testing.assert_array_equal(np.asarray(y_band), np.asarray(oracle))
         y_ref = ref.bitserial_conv_banded_ref(x, wp, kernel=kernel,
                                               stride=stride, w_bits=pw,
@@ -75,7 +75,7 @@ def test_banded_static_stride2_overlapping_bands():
     oracle = ref.bitserial_conv_ref(x, wp, kernel=5, stride=2, w_bits=8)
     for rpb in (2, 3, 5):
         y = bitserial_conv(x, wp, kernel=5, stride=2, w_bits=8, bn=8,
-                           rows_per_band=rpb)
+                           rows_per_band=rpb, interpret=True)
         np.testing.assert_array_equal(np.asarray(y), np.asarray(oracle))
 
 
@@ -92,7 +92,7 @@ def test_banded_static_all_zero_band():
     wp = bitpack.pack_weights(wq, pw)
     oracle = ref.bitserial_conv_ref(x, wp, kernel=3, stride=1, w_bits=pw)
     y = bitserial_conv(x, wp, kernel=3, stride=1, w_bits=pw, bn=8,
-                       rows_per_band=4)
+                       rows_per_band=4, interpret=True)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(oracle))
 
 
@@ -156,7 +156,7 @@ def test_dynamic_band_crossing_window_group():
     wdense = bitpack.unpack_weights(wp, pw).astype(jnp.int8)
     y_k = bitserial_conv_dynamic(xq.astype(jnp.int8), wdense, counts,
                                  kernel=3, stride=1, a_bits=pa,
-                                 group_size=gsz)
+                                 group_size=gsz, interpret=True)
     np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_full))
 
 

@@ -172,6 +172,22 @@ def test_guarded_accum_overflow_fails_loudly():
     assert gb.fallbacks_by_op == {}       # fail-loud, never fall back
 
 
+@pytest.mark.parametrize("platform,chain", [
+    ("cpu", ["pallas_interpret", "xla"]),
+    ("tpu", ["xla"]),
+])
+def test_default_fallback_chain_has_no_interpreter_on_tpu(monkeypatch,
+                                                          platform, chain):
+    """On a TPU a kernel Mosaic refuses falls straight to XLA: running
+    the Pallas interpreter there is never a recovery."""
+    monkeypatch.setattr(backendlib.jax, "default_backend", lambda: platform)
+    gb = backendlib.GuardedBackend("pallas_tpu")
+    assert [b.name for b in gb.chain[1:]] == chain
+    sess = loom.compile(configs.get("qwen3-1.7b", smoke=True),
+                        uniform_policy(8, 8), mode="serve_packed")
+    assert ServingSupervisor(sess).fallback_backends == chain
+
+
 def test_guarded_shape_guard_fails_loudly():
     xq, wp = _matmul_operands(k=16)
     gb = backendlib.GuardedBackend("xla")

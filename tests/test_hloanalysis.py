@@ -29,10 +29,7 @@ def test_dot_flops_match_xla_on_unrolled():
     ws = jax.ShapeDtypeStruct((4, 128, 128), jnp.float32)
     c = jax.jit(f).lower(x, ws).compile()
     ours = H.analyze_hlo(c.as_text()).flops
-    cost = c.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # jax<=0.4.x: [dict] per device
-        cost = cost[0]
-    xla = cost["flops"]
+    xla = c.cost_analysis()["flops"]
     # XLA counts tanh etc.; dots dominate. Expect within 10%.
     assert abs(ours / xla - 1) < 0.10, (ours, xla)
 
@@ -87,8 +84,9 @@ _SPMD_SCRIPT = textwrap.dedent("""
     import sys
     sys.path.insert(0, "src")
     from repro.launch import hloanalysis as H
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((8,), ("d",))
+    mesh = make_mesh((8,), ("d",))
     def f(x, w):
         y = x @ w                       # dp x replicated -> psum in bwd only
         return jnp.sum(y * y)

@@ -1,4 +1,6 @@
 """Per-kernel sweeps: pallas_call(interpret=True) vs the ref.py oracle."""
+import inspect
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -7,11 +9,25 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import bitpack, quantize as q
 from repro.kernels import ref
+from repro.kernels.bitserial_conv import (bitserial_conv,
+                                          bitserial_conv_dynamic,
+                                          bitserial_conv_wgroup)
 from repro.kernels.bitserial_matmul import bitserial_matmul, bitserial_matmul_dynamic
 from repro.kernels.dynamic_quant import dynamic_quant
 from repro.kernels.flash_attention import flash_attention
 
 jax.config.update("jax_platform_name", "cpu")
+
+
+@pytest.mark.parametrize("kernel", [
+    bitserial_matmul, bitserial_matmul_dynamic, bitserial_conv,
+    bitserial_conv_wgroup, bitserial_conv_dynamic, dynamic_quant,
+    flash_attention], ids=lambda f: f.__name__)
+def test_kernel_interpret_has_no_default(kernel):
+    """Callers choose the interpreter or Mosaic: a default would let a
+    chip run fall back to the interpreter unnoticed."""
+    p = inspect.signature(kernel).parameters["interpret"]
+    assert p.default is inspect.Parameter.empty
 
 
 def make_packed(k, n, w_bits, seed):
@@ -34,7 +50,7 @@ def test_bitserial_matmul_shape_sweep(m, k, n, w_bits):
     x = jnp.asarray(rng.integers(-128, 128, size=(m, k)), dtype=jnp.int8)
     wp, wq, _ = make_packed(k, n, w_bits, w_bits + 1)
     y = bitserial_matmul(x, wp, w_bits=w_bits, bm=min(8, m), bn=min(8, n),
-                         bk=min(32, k))
+                         bk=min(32, k), interpret=True)
     np.testing.assert_array_equal(np.asarray(y),
                                   np.asarray(ref.bitserial_matmul_ref(x, wp, w_bits)))
 
@@ -44,7 +60,7 @@ def test_bitserial_matmul_block_sweep(bm, bn, bk):
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.integers(-128, 128, size=(16, 64)), dtype=jnp.int8)
     wp, _, _ = make_packed(64, 32, 9, 7)
-    y = bitserial_matmul(x, wp, w_bits=9, bm=bm, bn=bn, bk=bk)
+    y = bitserial_matmul(x, wp, w_bits=9, bm=bm, bn=bn, bk=bk, interpret=True)
     np.testing.assert_array_equal(np.asarray(y),
                                   np.asarray(ref.bitserial_matmul_ref(x, wp, 9)))
 
@@ -58,7 +74,7 @@ def test_bitserial_matmul_property(w_bits, mkn):
     wq = jnp.asarray(rng.integers(q.qmin(w_bits), q.qmax(w_bits) + 1, size=(k, n)),
                      dtype=jnp.int32)
     wp = bitpack.pack_weights(wq, w_bits)
-    y = bitserial_matmul(x, wp, w_bits=w_bits, bm=m, bn=n, bk=k)
+    y = bitserial_matmul(x, wp, w_bits=w_bits, bm=m, bn=n, bk=k, interpret=True)
     np.testing.assert_array_equal(
         np.asarray(y),
         np.asarray(jnp.matmul(x.astype(jnp.int32), wq)))
@@ -76,7 +92,8 @@ def test_bitserial_matmul_dynamic_skips_planes():
         cols.append(rng.integers(-(1 << (int(c) - 1)), (1 << (int(c) - 1)), size=(k, bn)))
     wq = jnp.asarray(np.concatenate(cols, axis=1), dtype=jnp.int32)
     wp = bitpack.pack_weights(wq, pw)
-    y = bitserial_matmul_dynamic(x, wp, counts, w_bits=pw, bm=m, bn=bn, bk=32)
+    y = bitserial_matmul_dynamic(x, wp, counts, w_bits=pw, bm=m, bn=bn, bk=32,
+                                 interpret=True)
     np.testing.assert_array_equal(
         np.asarray(y), np.asarray(ref.bitserial_matmul_dynamic_ref(x, wp, counts, pw, bn)))
     np.testing.assert_array_equal(
@@ -108,7 +125,7 @@ def test_bitserial_matmul_dynamic_vs_ref(counts, pw):
     wp = bitpack.pack_weights(wq, pw)
     counts_arr = jnp.asarray(counts, dtype=jnp.int32)
     y = bitserial_matmul_dynamic(x, wp, counts_arr, w_bits=pw, bm=m, bn=bn,
-                                 bk=32)
+                                 bk=32, interpret=True)
     expect = ref.bitserial_matmul_dynamic_ref(x, wp, counts_arr, pw, bn)
     np.testing.assert_array_equal(np.asarray(y), np.asarray(expect))
     # zero-count tiles are exactly zero; the whole thing matches the
@@ -162,7 +179,8 @@ def test_pack_roundtrip_pw16():
 @pytest.mark.parametrize("bits", [4, 8])
 def test_dynamic_quant_sweep(m, k, g, bits):
     x = jnp.asarray(np.random.default_rng(m * k).normal(size=(m, k)).astype(np.float32))
-    xq, scale, eff = dynamic_quant(x, group_size=g, bits=bits, bm=min(4, m))
+    xq, scale, eff = dynamic_quant(x, group_size=g, bits=bits, bm=min(4, m),
+                                   interpret=True)
     rq, rs, re = ref.dynamic_quant_ref(x, g, bits)
     np.testing.assert_array_equal(np.asarray(xq), np.asarray(rq))
     np.testing.assert_allclose(np.asarray(scale), np.asarray(rs), rtol=1e-6)
@@ -172,7 +190,8 @@ def test_dynamic_quant_sweep(m, k, g, bits):
 def test_dynamic_quant_eff_bits_detects_small_groups():
     x = np.ones((1, 512), dtype=np.float32)
     x[0, 256:] = 100.0  # group 1 large, group 0 small relative to its own max
-    xq, scale, eff = dynamic_quant(jnp.asarray(x), group_size=256, bits=8, bm=1)
+    xq, scale, eff = dynamic_quant(jnp.asarray(x), group_size=256, bits=8, bm=1,
+                                   interpret=True)
     # per-group scaling -> both groups hit full 8-bit range
     assert int(eff[0, 0]) == 8 and int(eff[0, 1]) == 8
     np.testing.assert_allclose(float(scale[0, 1]) / float(scale[0, 0]), 100.0, rtol=1e-5)
@@ -191,7 +210,7 @@ def test_flash_attention_sweep(s, d, bq, bk, causal):
     q_ = jnp.asarray(rng.normal(size=shape).astype(np.float32))
     k_ = jnp.asarray(rng.normal(size=shape).astype(np.float32))
     v_ = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-    out = flash_attention(q_, k_, v_, causal=causal, bq=bq, bk=bk)
+    out = flash_attention(q_, k_, v_, causal=causal, bq=bq, bk=bk, interpret=True)
     expect = ref.flash_attention_ref(q_, k_, v_, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-5, atol=2e-5)
@@ -204,7 +223,8 @@ def test_flash_attention_sliding_window(window):
     q_ = jnp.asarray(rng.normal(size=shape).astype(np.float32))
     k_ = jnp.asarray(rng.normal(size=shape).astype(np.float32))
     v_ = jnp.asarray(rng.normal(size=shape).astype(np.float32))
-    out = flash_attention(q_, k_, v_, causal=True, window=window, bq=32, bk=32)
+    out = flash_attention(q_, k_, v_, causal=True, window=window, bq=32, bk=32,
+                          interpret=True)
     expect = ref.flash_attention_ref(q_, k_, v_, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                rtol=2e-5, atol=2e-5)
@@ -216,7 +236,7 @@ def test_flash_attention_bf16():
     q_ = jnp.asarray(rng.normal(size=shape), dtype=jnp.bfloat16)
     k_ = jnp.asarray(rng.normal(size=shape), dtype=jnp.bfloat16)
     v_ = jnp.asarray(rng.normal(size=shape), dtype=jnp.bfloat16)
-    out = flash_attention(q_, k_, v_, bq=32, bk=32)
+    out = flash_attention(q_, k_, v_, bq=32, bk=32, interpret=True)
     expect = ref.flash_attention_ref(q_, k_, v_)
     np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
                                np.asarray(expect, dtype=np.float32),

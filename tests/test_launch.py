@@ -11,6 +11,7 @@ from jax.sharding import PartitionSpec as PS
 from repro import configs
 from repro.dist import sharding
 from repro.launch import shapes
+from repro.launch.mesh import make_mesh
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -80,7 +81,7 @@ def test_rule_overrides_resolution():
     try:
         sharding.set_rule_overrides({"dp": (), "sp": ("data", "model")})
         import jax.sharding as js
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
         rules = sharding.rules_for_mesh(mesh)
         spec = sharding.resolve_spec(PS("dp", "sp", None), rules)
         assert spec == PS(None, ("data", "model"), None)
@@ -128,3 +129,24 @@ def test_dryrun_cell_subprocess(tmp_path):
     recs = [json.load(open(p)) for p in glob.glob(str(tmp_path) + "/*.json")]
     assert recs and recs[0]["n_devices"] == 256
     assert recs[0]["t_memory_s"] > 0 and recs[0]["flops"] > 0
+
+
+def test_compile_cache_dir_from_env_or_repo(monkeypatch, tmp_path):
+    """The cache directory is JAX_COMPILATION_CACHE_DIR when set (and no
+    other path is set then), else the fixed <repo>/.jax_cache."""
+    import os
+
+    from repro.launch import compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", None)
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir is None
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = compile_cache.enable()
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert path == os.path.join(repo, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

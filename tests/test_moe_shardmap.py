@@ -12,9 +12,10 @@ _SCRIPT = textwrap.dedent("""
     sys.path.insert(0, "src")
     import jax, jax.numpy as jnp, numpy as np
     import repro.api as loom
+    from repro.launch.mesh import make_mesh
     from repro.models import moe as MOE
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ec = loom.build_plan(None, mode="dense")
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(4, 16, 32)), jnp.float32)

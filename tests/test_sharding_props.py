@@ -9,6 +9,7 @@ from jax.sharding import PartitionSpec as PS
 from repro import configs
 from repro.dist import sharding
 from repro.launch import shapes
+from repro.launch.mesh import make_mesh
 
 jax.config.update("jax_platform_name", "cpu")
 
@@ -48,7 +49,7 @@ def test_resolve_spec_idempotent_on_resolved(entries):
 
 
 def _mesh_rules():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return sharding.rules_for_mesh(mesh)
 
 

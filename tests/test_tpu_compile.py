@@ -1,0 +1,112 @@
+"""Ahead-of-time Mosaic compiles of the serving kernels for one TPU v5e.
+
+Each test lowers one Pallas kernel with ``interpret=False`` at a real
+serving shape against a described (not attached) ``v5e:2x2`` topology
+and compiles it with the TPU compiler, which refuses what the chip
+would refuse: unsupported vector layouts, misaligned block shapes, VMEM
+overruns. Nothing runs, so these tests say nothing about results or
+time; the interpret-mode tests and the chip smoke run cover those.
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU library, and every pytest
+worker imports this file.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.api.backend import _pallas_blocks
+from repro.kernels.bitserial_conv import (bitserial_conv,
+                                          bitserial_conv_dynamic,
+                                          bitserial_conv_wgroup)
+from repro.kernels.bitserial_matmul import (bitserial_matmul,
+                                            bitserial_matmul_dynamic)
+from repro.kernels.dynamic_quant import dynamic_quant
+
+# qwen3-1.7b serving widths and the paper CNN's middle conv at 32 px.
+D_MODEL, D_FF, VOCAB, DECODE_M = 2048, 6144, 151936, 8
+CNN_B, CNN_HW, CNN_C, CNN_N = 64, 16, 32, 64
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means no TPU compiler
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep these compiles out of it.
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _compile(fn, sharding, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.mark.parametrize("n", [D_FF, VOCAB], ids=["ffn_up", "lm_head"])
+def test_bitserial_matmul_decode(one_chip, n):
+    bm, bn, bk = _pallas_blocks(DECODE_M, n, D_MODEL)
+    fn = functools.partial(bitserial_matmul, w_bits=8, bm=bm, bn=bn, bk=bk,
+                           interpret=False)
+    _compile(fn, one_chip, ((DECODE_M, D_MODEL), jnp.int8),
+             ((8, D_MODEL // 8, n), jnp.uint8))
+
+
+def test_bitserial_matmul_weight_groups(one_chip):
+    """The lm_head weight-group route: one count per 16 filters, 128-wide
+    N-tiles (the backend's static trimming call)."""
+    bm, bn, bk = _pallas_blocks(DECODE_M, VOCAB, D_MODEL)
+    fn = functools.partial(bitserial_matmul_dynamic, w_bits=8, group=16,
+                           bm=bm, bn=bn, bk=bk, interpret=False)
+    _compile(fn, one_chip, ((DECODE_M, D_MODEL), jnp.int8),
+             ((8, D_MODEL // 8, VOCAB), jnp.uint8),
+             ((VOCAB // 16,), jnp.int32))
+
+
+def _conv_shapes():
+    k8 = -(-9 * CNN_C // 8)
+    return (((CNN_B, CNN_HW, CNN_HW, CNN_C), jnp.int8),
+            ((8, k8, CNN_N), jnp.uint8))
+
+
+@pytest.mark.parametrize("rows_per_band", [None, 4])
+def test_bitserial_conv(one_chip, rows_per_band):
+    fn = functools.partial(bitserial_conv, kernel=3, stride=1, w_bits=8,
+                           rows_per_band=rows_per_band, interpret=False)
+    _compile(fn, one_chip, *_conv_shapes())
+
+
+def test_bitserial_conv_wgroup(one_chip):
+    fn = functools.partial(bitserial_conv_wgroup, kernel=3, stride=1,
+                           w_bits=8, w_group=16, bn=CNN_N, interpret=False)
+    _compile(fn, one_chip, *_conv_shapes(), ((CNN_N // 16,), jnp.int32))
+
+
+def test_bitserial_conv_dynamic(one_chip):
+    k8 = -(-9 * CNN_C // 8) * 8
+    groups = -(-CNN_HW * CNN_HW // 64)
+    fn = functools.partial(bitserial_conv_dynamic, kernel=3, stride=1,
+                           a_bits=8, group_size=64, interpret=False)
+    _compile(fn, one_chip, ((CNN_B, CNN_HW, CNN_HW, CNN_C), jnp.int8),
+             ((k8, CNN_N), jnp.int8), ((CNN_B, groups), jnp.int32))
+
+
+def test_dynamic_quant(one_chip):
+    fn = functools.partial(dynamic_quant, group_size=256, bits=8,
+                           interpret=False)
+    _compile(fn, one_chip, ((256, D_MODEL), jnp.float32))
