@@ -20,6 +20,11 @@ Built-ins:
     pallas_interpret Pallas kernels under interpret=True (CPU validation)
     pallas_tpu       Pallas kernels compiled by Mosaic (real TPU)
 
+The four plane ops take ``name``: the kernel's name in the compiled
+program (``bitserial_matmul_attn_q``), so a profiler trace attributes
+each kernel to its layer. The XLA oracle has no kernel and ignores it;
+None keeps the kernel's own name.
+
 ``register_backend`` admits out-of-tree substrates (a future Triton or
 CUDA port) without touching model code: implement the five ops, register
 under a name, pass ``backend="yourname"`` to ``loom.compile``.
@@ -101,7 +106,8 @@ class Backend:
 
     def matmul_planes(self, xq: jax.Array, w_packed: jax.Array, *,
                       w_bits: int, a_bits: int = 8, w_counts=None,
-                      w_group: int = 16) -> jax.Array:
+                      w_group: int = 16,
+                      name: str | None = None) -> jax.Array:
         """int8 [M, K] @ packed uint8 [Pw, K//8, N] -> exact int32 [M, N].
 
         ``w_counts`` (pack-time per-filter-group plane counts, Python
@@ -136,7 +142,7 @@ class Backend:
 
     def matmul_planes_dynamic(self, xq: jax.Array, w_packed: jax.Array,
                               plane_counts: jax.Array, *, w_bits: int,
-                              bn: int) -> jax.Array:
+                              bn: int, name: str | None = None) -> jax.Array:
         """Like matmul_planes but N-tile j executes only plane_counts[j]
         planes of the packed operand (2's complement at the effective
         width). ``bn`` is the N-tile width one count covers.
@@ -161,7 +167,7 @@ class Backend:
     def conv_planes(self, xq: jax.Array, w_packed: jax.Array, *, kernel: int,
                     stride: int, w_bits: int, a_bits: int,
                     conv_tile: int | None = None, w_counts=None,
-                    w_group: int = 16) -> jax.Array:
+                    w_group: int = 16, name: str | None = None) -> jax.Array:
         """Fused bit-serial "same" conv: int [B,H,W,C] x packed planes ->
         exact int32 [B, Ho, Wo, N]. No im2col patch tensor in HBM.
         ``conv_tile`` (rows per band) only matters to VMEM-constrained
@@ -198,7 +204,8 @@ class Backend:
     def conv_planes_dynamic(self, xq: jax.Array, w_packed: jax.Array,
                             counts: jax.Array, *, kernel: int, stride: int,
                             w_bits: int, a_bits: int, group_size: int,
-                            w_counts=None, w_group: int = 16) -> jax.Array:
+                            w_counts=None, w_group: int = 16,
+                            name: str | None = None) -> jax.Array:
         """Like conv_planes but each group of ``group_size`` output windows
         executes only counts[b, g] serial activation planes.
 
@@ -272,7 +279,7 @@ class PallasBackend(Backend):
         self.vmem_budget = vmem_budget
 
     def matmul_planes(self, xq, w_packed, *, w_bits, a_bits=8, w_counts=None,
-                      w_group=16):
+                      w_group=16, name=None):
         m, k = xq.shape
         n = w_packed.shape[-1]
         # All-full counts (nothing trimmable, e.g. random-init weights on
@@ -283,7 +290,8 @@ class PallasBackend(Backend):
         if w_counts is None or all(c >= w_bits for c in w_counts):
             bm, bn, bk = _pallas_blocks(m, n, k)
             return bitserial_matmul(xq, w_packed, w_bits=w_bits, bm=bm,
-                                    bn=bn, bk=bk, interpret=self.interpret)
+                                    bn=bn, bk=bk, interpret=self.interpret,
+                                    name=name)
         # Static weight-group trimming reuses the dynamic-precision kernel:
         # the packed operand here IS the weights and the counts are the
         # pack-time constants from the plan, one per group of w_group
@@ -298,20 +306,21 @@ class PallasBackend(Backend):
         bm, bn, bk = _pallas_blocks(m, n + npad, k)
         y = bitserial_matmul_dynamic(
             xq, wp, jnp.asarray(w_counts, jnp.int32), w_bits=w_bits,
-            group=w_group, bm=bm, bn=bn, bk=bk, interpret=self.interpret)
+            group=w_group, bm=bm, bn=bn, bk=bk, interpret=self.interpret,
+            name=name)
         return y[:, :n] if npad else y
 
     def matmul_planes_dynamic(self, xq, w_packed, plane_counts, *, w_bits,
-                              bn):
+                              bn, name=None):
         m, k = xq.shape
         n = w_packed.shape[-1]
         bm, _, bk = _pallas_blocks(m, n, k)
         return bitserial_matmul_dynamic(xq, w_packed, plane_counts,
                                         w_bits=w_bits, bm=bm, bn=bn, bk=bk,
-                                        interpret=self.interpret)
+                                        interpret=self.interpret, name=name)
 
     def conv_planes(self, xq, w_packed, *, kernel, stride, w_bits, a_bits,
-                    conv_tile=None, w_counts=None, w_group=16):
+                    conv_tile=None, w_counts=None, w_group=16, name=None):
         # Same all-full-counts no-op guard as matmul_planes: untrimmable
         # counts stay on the static kernel (one patch assembly per
         # band/N-tile at bn=128, plane loop unrolled in-body).
@@ -319,7 +328,7 @@ class PallasBackend(Backend):
             return bitserial_conv(xq.astype(jnp.int8), w_packed,
                                   kernel=kernel, stride=stride,
                                   w_bits=w_bits, rows_per_band=conv_tile,
-                                  interpret=self.interpret)
+                                  interpret=self.interpret, name=name)
         # Static weight-group trimming: the wgroup kernel's grid gains the
         # serial weight-plane axis, gated per N-tile by the pack-time
         # scalar-prefetch counts. Ragged last group: pad N with zero
@@ -332,12 +341,12 @@ class PallasBackend(Backend):
             xq.astype(jnp.int8), wp, jnp.asarray(w_counts, jnp.int32),
             kernel=kernel, stride=stride, w_bits=w_bits, w_group=w_group,
             bn=_pallas_blocks(1, n + npad, 1)[1], rows_per_band=conv_tile,
-            interpret=self.interpret)
+            interpret=self.interpret, name=name)
         return y[..., :n] if npad else y
 
     def conv_planes_dynamic(self, xq, w_packed, counts, *, kernel, stride,
                             w_bits, a_bits, group_size, w_counts=None,
-                            w_group=16):
+                            w_group=16, name=None):
         # Activations are the plane-serial operand here; weights ride as
         # dense int8 MXU passes. Pw > 8 splits into 7-bit int8-safe
         # subplanes whose shifted partials accumulate exactly (the same
@@ -359,7 +368,7 @@ class PallasBackend(Backend):
             part = bitserial_conv_dynamic(
                 xq.astype(jnp.int8), w_planes[i].astype(jnp.int8), counts,
                 kernel=kernel, stride=stride, a_bits=a_bits,
-                group_size=group_size, interpret=self.interpret)
+                group_size=group_size, interpret=self.interpret, name=name)
             part = part * shifts[i]
             y = part if y is None else y + part
         return y
@@ -567,7 +576,7 @@ class GuardedBackend(Backend):
     # -- guarded op surface -------------------------------------------------
 
     def matmul_planes(self, xq, w_packed, *, w_bits, a_bits=8, w_counts=None,
-                      w_group=16):
+                      w_group=16, name=None):
         k8 = self._check_packed_k(int(xq.shape[-1]), w_packed,
                                   "matmul_planes")
         guards.check_accum_bound(k8, a_bits, w_bits, "matmul_planes")
@@ -575,10 +584,10 @@ class GuardedBackend(Backend):
                              w_bits, "matmul_planes")
         return self._dispatch("matmul_planes", xq, w_packed, w_bits=w_bits,
                               a_bits=a_bits, w_counts=w_counts,
-                              w_group=w_group)
+                              w_group=w_group, name=name)
 
     def matmul_planes_dynamic(self, xq, w_packed, plane_counts, *, w_bits,
-                              bn):
+                              bn, name=None):
         # Dense operand rides int8 passes (<= 8 magnitude bits) on every
         # caller; the packed operand carries w_bits planes.
         k8 = self._check_packed_k(int(xq.shape[-1]), w_packed,
@@ -587,10 +596,10 @@ class GuardedBackend(Backend):
         self._check_plane_counts(plane_counts, w_bits,
                                  "matmul_planes_dynamic")
         return self._dispatch("matmul_planes_dynamic", xq, w_packed,
-                              plane_counts, w_bits=w_bits, bn=bn)
+                              plane_counts, w_bits=w_bits, bn=bn, name=name)
 
     def conv_planes(self, xq, w_packed, *, kernel, stride, w_bits, a_bits,
-                    conv_tile=None, w_counts=None, w_group=16):
+                    conv_tile=None, w_counts=None, w_group=16, name=None):
         kkc = kernel * kernel * int(xq.shape[-1])
         self._check_packed_k(kkc, w_packed, "conv_planes")
         guards.check_accum_bound(kkc, a_bits, w_bits, "conv_planes")
@@ -599,11 +608,11 @@ class GuardedBackend(Backend):
         return self._dispatch("conv_planes", xq, w_packed, kernel=kernel,
                               stride=stride, w_bits=w_bits, a_bits=a_bits,
                               conv_tile=conv_tile, w_counts=w_counts,
-                              w_group=w_group)
+                              w_group=w_group, name=name)
 
     def conv_planes_dynamic(self, xq, w_packed, counts, *, kernel, stride,
                             w_bits, a_bits, group_size, w_counts=None,
-                            w_group=16):
+                            w_group=16, name=None):
         kkc = kernel * kernel * int(xq.shape[-1])
         self._check_packed_k(kkc, w_packed, "conv_planes_dynamic")
         guards.check_accum_bound(kkc, a_bits, w_bits, "conv_planes_dynamic")
@@ -613,7 +622,7 @@ class GuardedBackend(Backend):
         return self._dispatch("conv_planes_dynamic", xq, w_packed, counts,
                               kernel=kernel, stride=stride, w_bits=w_bits,
                               a_bits=a_bits, group_size=group_size,
-                              w_counts=w_counts, w_group=w_group)
+                              w_counts=w_counts, w_group=w_group, name=name)
 
     def dynamic_quant(self, x2, *, group_size, bits):
         # A NaN/Inf activation quantizes to garbage silently; reject it

@@ -188,11 +188,11 @@ def _kernel(x_ref, wp_ref, out_ref, *, kernel: int, stride: int, w_bits: int,
 
 @functools.partial(jax.jit, static_argnames=("kernel", "stride", "w_bits",
                                              "bn", "rows_per_band",
-                                             "interpret"))
+                                             "interpret", "name"))
 def bitserial_conv(x: jax.Array, w_packed: jax.Array, *, kernel: int,
                    stride: int = 1, w_bits: int, bn: int = 128,
                    rows_per_band: int | None = None,
-                   interpret: bool) -> jax.Array:
+                   interpret: bool, name: str | None = None) -> jax.Array:
     """Fused bit-serial "same"-padded conv over packed weight planes.
 
     x: int8 [B, H, W, C]; w_packed: uint8 [Pw, ceil(k*k*C/8), N].
@@ -202,6 +202,8 @@ def bitserial_conv(x: jax.Array, w_packed: jax.Array, *, kernel: int,
     rows (None = one band = the whole map); banding never changes the
     result — it only bounds the per-step VMEM footprint
     (:func:`conv_vmem_bytes`). ``interpret=True`` validates on CPU.
+    ``name`` names the kernel's HLO instruction (a profiler trace lists
+    it as ``<name>.<n>``); None keeps ``bitserial_conv``.
     """
     assert kernel % 2 == 1, f"odd kernels only, got {kernel}"
     b, h, w, c = x.shape
@@ -233,6 +235,7 @@ def bitserial_conv(x: jax.Array, w_packed: jax.Array, *, kernel: int,
                                lambda i, j, l: (i, j, 0, 0, l)),
         out_shape=jax.ShapeDtypeStruct((b, nb, rpb, wo, n), jnp.int32),
         interpret=interpret,
+        name=name,
     )(xb, w_packed)
     return out.reshape(b, nb * rpb, wo, n)[:, :ho]
 
@@ -285,12 +288,14 @@ def _kernel_wg(counts_ref, x_ref, wp_ref, pw_ref, out_ref, patch_ref,
 
 @functools.partial(jax.jit, static_argnames=("kernel", "stride", "w_bits",
                                              "w_group", "bn",
-                                             "rows_per_band", "interpret"))
+                                             "rows_per_band", "interpret",
+                                             "name"))
 def bitserial_conv_wgroup(x: jax.Array, w_packed: jax.Array,
                           counts: jax.Array, *, kernel: int, stride: int = 1,
                           w_bits: int, w_group: int = 16, bn: int = 128,
                           rows_per_band: int | None = None,
-                          interpret: bool) -> jax.Array:
+                          interpret: bool,
+                          name: str | None = None) -> jax.Array:
     """Fused bit-serial conv with STATIC per-filter-group plane skipping.
 
     x: int8 [B, H, W, C]; w_packed: uint8 [Pw, ceil(k*k*C/8), N]; counts:
@@ -304,7 +309,9 @@ def bitserial_conv_wgroup(x: jax.Array, w_packed: jax.Array,
     group's weights fit in its count (the OR-tree guarantee); for
     arbitrary counts it matches the truncating oracle
     ``ref.bitserial_conv_wgroup_ref``. ``rows_per_band`` bands the grid
-    over output rows exactly as in the static kernel.
+    over output rows exactly as in the static kernel, and ``name``
+    names the kernel's HLO instruction as there (None keeps
+    ``bitserial_conv_wgroup``).
     """
     assert kernel % 2 == 1, f"odd kernels only, got {kernel}"
     b, h, w, c = x.shape
@@ -347,6 +354,7 @@ def bitserial_conv_wgroup(x: jax.Array, w_packed: jax.Array,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((b, nb, rpb, wo, n), jnp.int32),
         interpret=interpret,
+        name=name,
     )(tiles, xb, w_packed, weights)
     return out.reshape(b, nb * rpb, wo, n)[:, :ho]
 
@@ -403,11 +411,13 @@ def _kernel_dyn(counts_ref, x_ref, w_ref, out_ref, band_ref, rows_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("kernel", "stride", "a_bits",
-                                             "group_size", "interpret"))
+                                             "group_size", "interpret",
+                                             "name"))
 def bitserial_conv_dynamic(x: jax.Array, wq: jax.Array, counts: jax.Array, *,
                            kernel: int, stride: int = 1, a_bits: int,
                            group_size: int = 256,
-                           interpret: bool) -> jax.Array:
+                           interpret: bool,
+                           name: str | None = None) -> jax.Array:
     """Fused "same"-padded conv with runtime activation-plane trimming.
 
     x: int8 [B, H, W, C]; wq: int8 [K8, N] — the UNPACKED weights (or one
@@ -421,7 +431,9 @@ def bitserial_conv_dynamic(x: jax.Array, wq: jax.Array, counts: jax.Array, *,
     (per-group work ~ group_size + Wo, NOT Ho*Wo). Returns int32
     [B, Ho, Wo, N], bit-identical to the static conv whenever every
     group's values fit in its count (2's-complement truncation at the
-    effective width is value-preserving).
+    effective width is value-preserving). ``name`` names the kernel's
+    HLO instruction, as in :func:`bitserial_conv` (None keeps
+    ``bitserial_conv_dynamic``).
     """
     assert kernel % 2 == 1, f"odd kernels only, got {kernel}"
     b, h, w, c = x.shape
@@ -464,5 +476,6 @@ def bitserial_conv_dynamic(x: jax.Array, wq: jax.Array, counts: jax.Array, *,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((b, ng * gsz, n), jnp.int32),
         interpret=interpret,
+        name=name,
     )(counts, xb, wq)
     return out[:, :nwin].reshape(b, ho, wo, n)

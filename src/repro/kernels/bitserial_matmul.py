@@ -78,15 +78,18 @@ def _kernel(x_ref, wp_ref, out_ref, acc_ref, *, w_bits: int, nk: int):
         out_ref[...] = acc_ref[...]
 
 
-@functools.partial(jax.jit, static_argnames=("w_bits", "bm", "bn", "bk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("w_bits", "bm", "bn", "bk",
+                                             "interpret", "name"))
 def bitserial_matmul(x: jax.Array, w_packed: jax.Array, *, w_bits: int,
                      bm: int = 128, bn: int = 128, bk: int = 512,
-                     interpret: bool) -> jax.Array:
+                     interpret: bool, name: str | None = None) -> jax.Array:
     """x: int8 [M, K]; w_packed: uint8 [Pw, K//8, N] -> int32 [M, N].
 
     Integer-exact: result == x.astype(i32) @ unpack(w_packed).astype(i32).
     ``interpret=True`` runs the kernel on CPU (validation); Mosaic
-    compiles it for the TPU with ``interpret=False``.
+    compiles it for the TPU with ``interpret=False``. ``name`` names the
+    kernel's HLO instruction (a profiler trace lists it as
+    ``<name>.<n>``); None keeps ``bitserial_matmul``.
     """
     m, k = x.shape
     pw, k8, n = w_packed.shape
@@ -107,6 +110,7 @@ def bitserial_matmul(x: jax.Array, w_packed: jax.Array, *, w_bits: int,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name=name,
     )(x, w_packed)
 
 
@@ -165,12 +169,13 @@ def live_plane(p, count):
 
 
 @functools.partial(jax.jit, static_argnames=("w_bits", "group", "bm", "bn",
-                                             "bk", "interpret"))
+                                             "bk", "interpret", "name"))
 def bitserial_matmul_dynamic(x: jax.Array, w_packed: jax.Array,
                              plane_counts: jax.Array, *, w_bits: int,
                              group: int | None = None,
                              bm: int = 128, bn: int = 128, bk: int = 512,
-                             interpret: bool) -> jax.Array:
+                             interpret: bool,
+                             name: str | None = None) -> jax.Array:
     """Like bitserial_matmul but column group g executes only
     plane_counts[g] planes, the (count-1)-th negated (two's complement
     at the effective width). A group is ``group`` columns (default: one
@@ -178,7 +183,9 @@ def bitserial_matmul_dynamic(x: jax.Array, w_packed: jax.Array,
     ``bn`` stays 128-aligned however narrow the groups are. Values that
     fit their group's count give the untrimmed result; for arbitrary
     counts this is the truncating oracle
-    ``ref.bitserial_matmul_wgroup_ref``."""
+    ``ref.bitserial_matmul_wgroup_ref``. ``name`` names the kernel's HLO
+    instruction, as in :func:`bitserial_matmul` (None keeps
+    ``bitserial_matmul_dynamic``)."""
     m, k = x.shape
     pw, k8, n = w_packed.shape
     assert pw == w_bits and k8 * 8 == k
@@ -208,4 +215,5 @@ def bitserial_matmul_dynamic(x: jax.Array, w_packed: jax.Array,
         grid_spec=gs,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name=name,
     )(tiles, x, w_packed, weights)

@@ -20,10 +20,16 @@ from repro.core import bitpack, dynamic, quantize as q, weightgroups
 from repro.kernels import ref
 
 
+def kernel_name(kernel: str, layer: str) -> str | None:
+    """The name of ``kernel`` serving ``layer`` in the compiled program
+    (``bitserial_matmul_attn_q``); no layer keeps the kernel's own."""
+    return f"{kernel}_{layer}" if layer else None
+
+
 def loom_linear_serve(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array,
                       *, a_bits: int, w_bits: int, backend=None,
                       w_counts=None, w_group: int = 16,
-                      a_axis: int | None = -1) -> jax.Array:
+                      a_axis: int | None = -1, layer: str = "") -> jax.Array:
     """Serving-path linear: activations dynamically quantized to a_bits,
     weights pre-packed bit-serially. Output in x.dtype.
 
@@ -35,6 +41,7 @@ def loom_linear_serve(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array,
     ``a_axis``: activation-quantization axis. Default -1 = per-row scales
     (each token row on its own grid — continuous batching's byte-identity
     bar); None = one per-tensor scale (the conv/im2col lowering's grid).
+    ``layer`` names the kernel after the layer (:func:`kernel_name`).
     """
     be = resolve_backend(backend)
     lead = x.shape[:-1]
@@ -51,13 +58,12 @@ def loom_linear_serve(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array,
     # (continuous batching's byte-identity bar). For batch-1 the row scale
     # IS the tensor scale.
     xq, x_scale = q.quantize(x2, a_bits, axis=a_axis)
-    # Trimming kwargs only travel when counts exist: out-of-tree Backend
-    # subclasses overriding the pre-trimming signatures keep working on
-    # the untrimmed path.
+    # Trimming kwargs only travel when counts exist; ``name`` always
+    # does, so a Backend's plane ops must accept it.
     trim = {} if w_counts is None else dict(a_bits=a_bits, w_counts=w_counts,
                                             w_group=w_group)
     y = be.matmul_planes(xq.astype(jnp.int8), w_packed, w_bits=w_bits,
-                         **trim)
+                         name=kernel_name("bitserial_matmul", layer), **trim)
     # Single cast at the end: the int32 accumulate is scaled in f32 and
     # dropped straight to x.dtype (bf16 in, bf16 out — no double round).
     out = (y * (x_scale * w_scale).astype(jnp.float32)).astype(x.dtype)
@@ -73,7 +79,8 @@ def loom_linear_serve_dynamic(x: jax.Array, w_packed: jax.Array,
                               w_bits: int, group_size: int = 256,
                               backend=None, w_counts=None,
                               w_group: int = 16,
-                              a_axis: int | None = -1) -> jax.Array:
+                              a_axis: int | None = -1,
+                              layer: str = "") -> jax.Array:
     """Dynamic-precision serving linear: runtime activation-plane trimming.
 
     Loom's Lascorz-style path: activations are quantized on the SAME
@@ -102,6 +109,7 @@ def loom_linear_serve_dynamic(x: jax.Array, w_packed: jax.Array,
     for OR-tree counts, so the composition stays bit-identical to the
     static path); the modeled pass count becomes
     mean_Pa_eff x mean_Pw_eff over the group intersections.
+    ``layer`` names the kernel after the layer (:func:`kernel_name`).
     """
     be = resolve_backend(backend)
     lead = x.shape[:-1]
@@ -132,11 +140,12 @@ def loom_linear_serve_dynamic(x: jax.Array, w_packed: jax.Array,
         # int8 MXU ABI: 7-bit subplanes keep every plane value in int8
         # range (an unsigned 8-bit low plane would not fit).
         w_planes, shifts = q.group_planes(wq, w_bits, 7)
+    name = kernel_name("bitserial_matmul", layer)
     yt = None
     for i in range(w_planes.shape[0]):
         part = be.matmul_planes_dynamic(
             w_planes[i].T.astype(jnp.int8), x_packed, counts,
-            w_bits=a_bits, bn=g)                                # [N, mp]
+            w_bits=a_bits, bn=g, name=name)                     # [N, mp]
         part = part * shifts[i]
         yt = part if yt is None else yt + part
     y = yt.T[:m]
@@ -213,7 +222,7 @@ def int_conv_same(x_int: jax.Array, w4: jax.Array, stride: int,
 def loom_conv_serve(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array,
                     *, kernel: int, stride: int, a_bits: int, backend=None,
                     conv_tile: int | None = None, w_counts=None,
-                    w_group: int = 16) -> jax.Array:
+                    w_group: int = 16, layer: str = "") -> jax.Array:
     """Serving-path fused conv: the CVL execution path.
 
     x: [B, H, W, C] float; w_packed: uint8 [Pw, ceil(k*k*C/8), N] in the
@@ -224,6 +233,7 @@ def loom_conv_serve(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array,
     Output in x.dtype. ``w_counts``/``w_group``: pack-time per-filter-group
     weight plane counts from the plan — each filter group then executes
     only its effective planes, bit-identically to the untrimmed path.
+    ``layer`` names the kernel after the layer (:func:`kernel_name`).
     """
     be = resolve_backend(backend)
     w_bits = w_packed.shape[0]
@@ -236,7 +246,7 @@ def loom_conv_serve(x: jax.Array, w_packed: jax.Array, w_scale: jax.Array,
                                             w_group=w_group)
     y = be.conv_planes(xq, w_packed, kernel=kernel, stride=stride,
                        w_bits=w_bits, a_bits=a_bits, conv_tile=conv_tile,
-                       **trim)
+                       name=kernel_name("bitserial_conv", layer), **trim)
     return (y * (x_scale * w_scale).astype(jnp.float32)).astype(x.dtype)
 
 
@@ -244,7 +254,7 @@ def loom_conv_serve_dynamic(x: jax.Array, w_packed: jax.Array,
                             w_scale: jax.Array, *, kernel: int, stride: int,
                             a_bits: int, group_size: int = 256,
                             backend=None, w_counts=None,
-                            w_group: int = 16) -> jax.Array:
+                            w_group: int = 16, layer: str = "") -> jax.Array:
     """Dynamic-precision serving conv: runtime activation-plane trimming.
 
     The CVL analogue of :func:`loom_linear_serve_dynamic`: activations are
@@ -262,7 +272,8 @@ def loom_conv_serve_dynamic(x: jax.Array, w_packed: jax.Array,
     trimming in (pack-time counts from the plan): the backend truncates
     each filter group's weights at its effective width — bit-identical
     composition for OR-tree counts, modeled passes
-    mean_Pa_eff x mean_Pw_eff.
+    mean_Pa_eff x mean_Pw_eff. ``layer`` names the kernel after the
+    layer (:func:`kernel_name`).
     """
     be = resolve_backend(backend)
     w_bits = w_packed.shape[0]
@@ -277,7 +288,9 @@ def loom_conv_serve_dynamic(x: jax.Array, w_packed: jax.Array,
                                             w_group=w_group)
     y = be.conv_planes_dynamic(xq, w_packed, counts, kernel=kernel,
                                stride=stride, w_bits=w_bits, a_bits=a_bits,
-                               group_size=gsz, **trim)
+                               group_size=gsz,
+                               name=kernel_name("bitserial_conv", layer),
+                               **trim)
     return (y * (x_scale * w_scale).astype(jnp.float32)).astype(x.dtype)
 
 

@@ -144,12 +144,12 @@ def _linear_packed(p, x, lp, be):
             x, p["w_packed"], p["w_scale"], a_bits=lp.a_bits,
             w_bits=p["w_packed"].shape[0], group_size=lp.group_size,
             backend=be, w_counts=lp.w_group_counts, w_group=lp.w_group,
-            a_axis=_token_quant_axis(x))
+            a_axis=_token_quant_axis(x), layer=lp.name)
     return ops.loom_linear_serve(
         x, p["w_packed"], p["w_scale"], a_bits=lp.a_bits,
         w_bits=p["w_packed"].shape[0], backend=be,
         w_counts=lp.w_group_counts, w_group=lp.w_group,
-        a_axis=_token_quant_axis(x))
+        a_axis=_token_quant_axis(x), layer=lp.name)
 
 
 _LINEAR_ROUTES = {
@@ -217,13 +217,13 @@ def _conv_packed(p, x, kernel, stride, lp, xplan):
             x, p["w_packed"], p["w_scale"], kernel=kernel, stride=stride,
             a_bits=lp.a_bits, group_size=lp.group_size,
             backend=xplan.backend, w_counts=lp.w_group_counts,
-            w_group=lp.w_group)
+            w_group=lp.w_group, layer=lp.name)
     tile = xplan.conv_tile(lp, x.shape[1], x.shape[2], x.shape[3],
                            p["w_packed"].shape[-1], p["w_packed"].shape[0])
     return ops.loom_conv_serve(
         x, p["w_packed"], p["w_scale"], kernel=kernel, stride=stride,
         a_bits=lp.a_bits, backend=xplan.backend, conv_tile=tile,
-        w_counts=lp.w_group_counts, w_group=lp.w_group)
+        w_counts=lp.w_group_counts, w_group=lp.w_group, layer=lp.name)
 
 
 _CONV_ROUTES = {

@@ -68,6 +68,24 @@ same prompt — regardless of co-batched traffic. The parity tests in
 and the fault-free, no-deadline path is byte-identical with or without
 the watchdog (the watched call is the same computation).
 
+Profiler spans: every step writes host spans into the profiler's trace
+(``jax.profiler.TraceAnnotation``; without a running profiler a span
+costs about a microsecond of host time, so they are always on). ``loom.engine.step``
+(a step annotation, stats ``step_num``, ``n_active``) holds one span
+per phase:
+
+  * ``loom.engine.admit`` (``n_admitted``): retirements and the
+    scheduler's assembly, holding per admission ``loom.engine.prefill``
+    (``request_id``, ``slot``, ``prompt_len``: batch-1 cache, prefill
+    and first-token read) and ``loom.engine.scatter`` (``request_id``,
+    ``slot``: the copy into the pool);
+  * ``loom.engine.decode`` (``n_active``): the host inputs and the
+    batched decode's dispatch;
+  * ``loom.engine.sync``: the argmax and its copy to the host, where the
+    host waits for the device;
+  * ``loom.engine.emit``: the slot loop, retirements, the shadow audit's
+    drain when it is on, and the serving gauges.
+
 Fault composition (with or without a :class:`ServingSupervisor`): the
 decode jit DONATES the cache, so a fault that surfaces after execution
 (e.g. NaN poisoning) leaves the old pool unusable — a naive step retry
@@ -90,6 +108,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from repro.api import guards
 from repro.runtime import faults
@@ -167,6 +186,7 @@ class BatchingEngine:
         self._tok = np.zeros(self.max_batch, np.int32)
         self._pos = np.zeros(self.max_batch, np.int32)
         self._watchdog: concurrent.futures.ThreadPoolExecutor | None = None
+        self._n_steps = 0
         self._n_decode_steps = 0
         self._occ_sum = 0
         self._busy_s = 0.0
@@ -256,17 +276,23 @@ class BatchingEngine:
             raise
 
     def _step_inner(self) -> bool:
-        t0 = time.monotonic()
-        self._integrity_tick()
-        self._retire_cancelled()
-        self._retire_expired(t0)
-        self._admit(t0)
-        if self.active:
-            self._decode_once()
-        self._audit_tick()
-        self._busy_s += time.monotonic() - t0
-        self._feed_stats()
-        return bool(self.active) or self.scheduler.depth > 0
+        with StepTraceAnnotation("loom.engine.step", step_num=self._n_steps,
+                                 n_active=len(self.active)):
+            self._n_steps += 1
+            t0 = time.monotonic()
+            self._integrity_tick()
+            with TraceAnnotation("loom.engine.admit") as span:
+                self._retire_cancelled()
+                self._retire_expired(t0)
+                span.set_metadata(n_admitted=self._admit(t0))
+            toks = self._decode_once() if self.active else None
+            with TraceAnnotation("loom.engine.emit"):
+                if toks is not None:
+                    self._emit_step(toks)
+                self._audit_tick()
+                self._busy_s += time.monotonic() - t0
+                self._feed_stats()
+            return bool(self.active) or self.scheduler.depth > 0
 
     def run(self, max_steps: int | None = None) -> None:
         """Drive :meth:`step` until the queue and the batch drain."""
@@ -406,7 +432,8 @@ class BatchingEngine:
                 f"after {req.n_emitted}/{req.gen_len} tokens (partial "
                 f"tokens retained on the stream)"))
 
-    def _admit(self, now: float | None = None) -> None:
+    def _admit(self, now: float | None = None) -> int:
+        """Shed, drop and admit queued requests; the number admitted."""
         admitted, dropped, expired = self.scheduler.assemble(
             self.pool.n_free, now)
         for req in dropped:
@@ -422,6 +449,7 @@ class BatchingEngine:
             self._place(req)
         if admitted or dropped or expired:
             self.stats.queue_depth = self.scheduler.depth
+        return len(admitted)
 
     def _place(self, req: Request) -> None:
         """Prefill ``req`` into a free slot (bounded per-request retries);
@@ -449,22 +477,28 @@ class BatchingEngine:
             raise ValueError(
                 f"request {req.request_id}: prompt_len {s} + gen_len "
                 f"{req.gen_len} exceeds the pool's max_seq {self.max_seq}")
-        tokens = jnp.asarray(req.prompt[None, :], jnp.int32)
-        attempt = 0
-        while True:
-            try:
-                cache1 = self.session.init_cache(1, self.max_seq)
-                logits, cache1 = self.session.prefill(tokens, cache=cache1)
-                tok0 = int(jnp.argmax(logits[:, 0], axis=-1)[0])
-                break
-            except _retryable() as exc:
-                if attempt >= self.prefill_retries:
-                    raise
-                attempt += 1
-                self.stats.n_retries += 1
-                self._degrade(exc)
-                time.sleep(self.backoff_s * (2 ** (attempt - 1)))
-        self.pool.scatter_prefill(req.slot, cache1)
+        with TraceAnnotation("loom.engine.prefill",
+                             request_id=req.request_id, slot=req.slot,
+                             prompt_len=s):
+            tokens = jnp.asarray(req.prompt[None, :], jnp.int32)
+            attempt = 0
+            while True:
+                try:
+                    cache1 = self.session.init_cache(1, self.max_seq)
+                    logits, cache1 = self.session.prefill(tokens,
+                                                          cache=cache1)
+                    tok0 = int(jnp.argmax(logits[:, 0], axis=-1)[0])
+                    break
+                except _retryable() as exc:
+                    if attempt >= self.prefill_retries:
+                        raise
+                    attempt += 1
+                    self.stats.n_retries += 1
+                    self._degrade(exc)
+                    time.sleep(self.backoff_s * (2 ** (attempt - 1)))
+        with TraceAnnotation("loom.engine.scatter",
+                             request_id=req.request_id, slot=req.slot):
+            self.pool.scatter_prefill(req.slot, cache1)
         req.pos = s
         self._emit(req, tok0)
 
@@ -507,14 +541,24 @@ class BatchingEngine:
                 f"decode step exceeded step_timeout_s="
                 f"{self.step_timeout_s}") from None
 
-    def _decode_once(self) -> None:
+    def _decode_once(self) -> np.ndarray | None:
+        """One batched decode; its argmax tokens on the host, or None
+        when the step faulted and the engine restarted instead."""
         try:
-            logits, cache = self._watched_decode()
-            self.pool.cache = cache
-            toks = np.asarray(jnp.argmax(logits, axis=-1).astype(jnp.int32))
+            with TraceAnnotation("loom.engine.decode",
+                                 n_active=len(self.active)):
+                logits, cache = self._watched_decode()
+                self.pool.cache = cache
+            with TraceAnnotation("loom.engine.sync"):
+                toks = np.asarray(
+                    jnp.argmax(logits, axis=-1).astype(jnp.int32))
         except _retryable() as exc:
             self._restart(exc)
-            return
+            return None
+        return toks
+
+    def _emit_step(self, toks: np.ndarray) -> None:
+        """Stream each active slot's token; retire finished requests."""
         self._consec_restarts = 0
         self._n_decode_steps += 1
         self._occ_sum += len(self.active)

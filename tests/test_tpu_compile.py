@@ -13,6 +13,7 @@ worker imports this file.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -110,3 +111,86 @@ def test_dynamic_quant(one_chip):
     fn = functools.partial(dynamic_quant, group_size=256, bits=8,
                            interpret=False)
     _compile(fn, one_chip, ((256, D_MODEL), jnp.float32))
+
+
+# -- kernel names: each packed kernel is named after its layer ----------------
+
+LM_CLASSES = ("attn_q", "attn_k", "attn_v", "attn_o", "ffn_gate", "ffn_up",
+              "ffn_down", "lm_head")
+
+
+def _on(sharding, tree):
+    return jax.tree.map(lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                                       sharding=sharding),
+                        tree)
+
+
+def _custom_calls(text: str, kernel: str) -> list:
+    """Names of the ``kernel*`` custom calls of a compiled program."""
+    return re.findall(rf"%({kernel}[\w]*?)(?:\.\d+)? = [^\n]*custom-call\(",
+                      text)
+
+
+def _lm_policy(name):
+    from repro.core.policy import uniform_policy
+    return {"static": uniform_policy(8, 8),
+            "dynamic_a": uniform_policy(8, 8, dynamic_a=True),
+            "w_group": uniform_policy(8, 8, w_group=16)}[name]
+
+
+@pytest.mark.parametrize("policy", ["static", "dynamic_a", "w_group"])
+def test_decode_step_names_kernels_by_layer(one_chip, policy):
+    """One packed decode step of the smoke qwen3 on pallas_tpu: every
+    linear class compiles to one custom call named
+    ``bitserial_matmul_<class>``, on each route (static planes, runtime
+    activation trimming, and the lm_head's weight-group route)."""
+    from repro import configs
+    from repro.api.plan import build_plan
+    from repro.api.session import _jit_lm
+    from repro.launch import shapes
+    from repro.models import model as M
+    cfg = configs.get("qwen3-1.7b", smoke=True)
+    pol = _lm_policy(policy)
+    plan = build_plan(cfg, pol, "serve_packed", "pallas_tpu")
+    if policy == "w_group":
+        # pack-time counts with one trimmable group: the lm_head takes the
+        # weight-group kernel, as a trimmed checkpoint's would
+        plan.layer("lm_head")
+        plan.set_weight_counts("lm_head", "linear",
+                               (7,) + (8,) * (cfg.vocab // 16 - 1))
+    params, _ = shapes.param_structs(cfg, serving_mode="serve_packed",
+                                     policy=pol)
+    cache = jax.eval_shape(lambda: M.init_cache(cfg, DECODE_M, cfg.max_seq))
+    tok = jax.ShapeDtypeStruct((DECODE_M,), jnp.int32, sharding=one_chip)
+    decode = _jit_lm(cfg, plan, None, None, None)[1]
+    text = decode.lower(_on(one_chip, params), tok, tok,
+                        _on(one_chip, cache)).compile().as_text()
+    names = _custom_calls(text, "bitserial_matmul")
+    assert sorted(names) == sorted(f"bitserial_matmul_{c}"
+                                   for c in LM_CLASSES), names
+
+
+def test_classify_names_convs_by_layer(one_chip):
+    """A small CNN's packed classify on pallas_tpu: each fused conv is
+    ``bitserial_conv_<layer>`` and the FC ``bitserial_matmul_fc0``."""
+    from repro.api.plan import build_plan
+    from repro.core.policy import uniform_policy
+    from repro.launch import shapes
+    from repro.models import cnn
+    from repro.models import model as M
+    cfg = cnn.CNNConfig(img=16, convs=(cnn.ConvSpec("conv1", 32, 3, pool=2),
+                                       cnn.ConvSpec("conv2", 64, 3, pool=2)),
+                        fcs=(10,))
+    pol = uniform_policy(8, 8)
+    plan = build_plan(cfg, pol, "serve_packed", "pallas_tpu")
+    params, specs = shapes._eval_shape_with_specs(
+        lambda: cnn.init_params(jax.random.PRNGKey(0), cfg))
+    params, _ = M.convert_structs_for_serving(params, specs, pol,
+                                              "serve_packed")
+    x = jax.ShapeDtypeStruct((CNN_B, 16, 16, 3), jnp.float32,
+                             sharding=one_chip)
+    text = jax.jit(lambda p, x: cnn.forward(p, cfg, x, plan)).lower(
+        _on(one_chip, params), x).compile().as_text()
+    assert sorted(_custom_calls(text, "bitserial_conv")) == [
+        "bitserial_conv_conv1", "bitserial_conv_conv2"]
+    assert _custom_calls(text, "bitserial_matmul") == ["bitserial_matmul_fc0"]
