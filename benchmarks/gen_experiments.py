@@ -72,17 +72,16 @@ CHAINS = {
     "C": ("llama3_405b", "decode_32k", [
         ("baseline (dense bf16, FSDP serving)", "", "dense"),
         ("+ pinseq", "pinseq", "dense"),
-        ("+ gqa (no KV repeat)", "pinseq-gqa", "dense"),
-        ("+ maskupd", "pinseq-gqa-maskupd", "dense"),
-        ("+ 2D-TP serving", "pinseq-gqa-maskupd-2dtp", "dense"),
-        ("+ int8 weights (paper LM_8b)", "pinseq-gqa-maskupd-2dtp",
+        ("+ maskupd", "pinseq-maskupd", "dense"),
+        ("+ 2D-TP serving", "pinseq-maskupd-2dtp", "dense"),
+        ("+ int8 weights (paper LM_8b)", "pinseq-maskupd-2dtp",
          "serve_int8"),
-        ("+ int8 KV cache (paper on KV)", "pinseq-gqa-maskupd-kv8-2dtp",
+        ("+ int8 KV cache (paper on KV)", "pinseq-maskupd-kv8-2dtp",
          "serve_int8"),
         ("+ int8 attention math",
-         "pinseq-gqa-maskupd-kv8-attnint8-2dtp", "serve_int8"),
+         "pinseq-maskupd-kv8-attnint8-2dtp", "serve_int8"),
         ("(bit-packed weights, XLA-oracle)",
-         "pinseq-gqa-maskupd-kv8-2dtp", "serve_packed"),
+         "pinseq-maskupd-kv8-2dtp", "serve_packed"),
     ]),
 }
 

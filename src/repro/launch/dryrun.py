@@ -147,8 +147,6 @@ def apply_opts(cfg, opts):
             cfg = dc.replace(cfg, decode_pin_seq=True)
         elif o == "kv8":
             cfg = dc.replace(cfg, kv_cache_bits=8)
-        elif o == "gqa":
-            cfg = dc.replace(cfg, gqa_decode=True)
         elif o == "maskupd":
             cfg = dc.replace(cfg, mask_cache_update=True)
         elif o == "kvrep":
@@ -310,7 +308,7 @@ def repair_json(out_dir: str = RESULTS_DIR):
         tag_opts = tuple(
             o for o in rec.get("tag", "").split("-")
             if o in ("flashvjp", "rematdots", "rematnone", "moedff", "moeep",
-                     "moesm", "kvcol", "kvrep", "pinseq", "kv8", "gqa",
+                     "moesm", "kvcol", "kvrep", "pinseq", "kv8",
                      "maskupd", "attnint8") or o.startswith("block"))
         cfg = apply_opts(configs.get(rec["arch"]), tag_opts)
         cell = shapes.SHAPES[rec["shape"]]
@@ -352,7 +350,7 @@ def main(argv=None):
     ap.add_argument("--serve-2d-tp", action="store_true")
     ap.add_argument("--opt", default="",
                     help="comma list: flashvjp,rematdots,rematnone,"
-                         "moedff,moeep,kvcol,kvrep,pinseq,kv8,gqa,maskupd")
+                         "moedff,moeep,kvcol,kvrep,pinseq,kv8,maskupd")
     ap.add_argument("--profile", action="store_true",
                     help="attach per-op memory/collective attribution")
     ap.add_argument("--out-dir", default=RESULTS_DIR)

@@ -41,7 +41,6 @@ class AttnConfig:
     flash_vjp: bool = False            # memory-efficient custom backward
     kv_col_parallel: bool = False      # kv projections column-parallel
     decode_pin_seq: bool = False       # pin cache seq-sharding in decode
-    gqa_decode: bool = False           # grouped decode einsum (no KV repeat)
     mask_cache_update: bool = False    # where()-based shard-local cache write
     kv_replicated: bool = False        # kv projections replicated over tp
     attn_int8: bool = False            # integer QK/PV dots on the int8 cache
@@ -354,9 +353,9 @@ def _mask_update(buf, new, slot):
 def _row_update(buf, new, slot):
     """Per-ROW one-slot write: buf [B,S,...], new [B,1,...], slot [B].
 
-    Each batch row writes its own slot — dynamic_update_slice cannot
-    express per-row indices, so this is a where() against a [B,S] hit
-    mask (elementwise, shard-friendly like _mask_update)."""
+    The where() counterpart of the in-place scatter for seq-sharded
+    caches: elementwise against a [B,S] hit mask, shard-local like
+    _mask_update."""
     s_cache = buf.shape[1]
     hit = (jnp.arange(s_cache)[None, :] == slot[:, None]).reshape(
         buf.shape[:2] + (1,) * (buf.ndim - 2))
@@ -375,46 +374,49 @@ def _write_slot_pos(sp, pos, slot):
     return jnp.where(hit, jnp.asarray(pos, jnp.int32), sp)
 
 
-def cache_update(cache: dict, cfg: AttnConfig, k_new, v_new, pos):
-    """Insert one token's K/V at absolute position ``pos`` (ring for SWA).
+def layer_cache(cache: dict, layer) -> dict:
+    """Layer ``layer`` of a stacked cache (leaves [L, B, ...]). Each leaf
+    is a dynamic slice, which XLA fuses into the op that reads it."""
+    return {n: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
+            for n, a in cache.items()}
 
-    ``pos`` may be a scalar (whole batch at one position) or a [B] vector
-    (continuous batching: each row decodes at its own position; writes go
-    to per-row slots via masked where()-updates)."""
-    s_cache = cache["k"].shape[1]
+
+def cache_update(cache: dict, cfg: AttnConfig, k_new, v_new, pos, layer):
+    """Insert one token's K/V at absolute position ``pos`` (ring for SWA)
+    into layer ``layer`` of the stacked cache (leaves [L, B, S_cache, ...]).
+
+    ``pos`` is a scalar (whole batch at one position) or a [B] vector
+    (continuous batching: each row at its own position). Each row's K, V,
+    int8 scales and position go in at (layer, row, pos % S_cache) with
+    one scatter per leaf, which XLA applies in place to the decode loop's
+    carried buffer. ``cfg.mask_cache_update`` keeps the where() write
+    over the layer instead: a scatter or dynamic_update_slice on a
+    seq-sharded cache cannot be partitioned and replicates it."""
     pos = jnp.asarray(pos, jnp.int32)
-    slot = pos % s_cache
-    per_row = pos.ndim == 1
-    if per_row or cfg.mask_cache_update:
-        upd = _row_update if per_row else _mask_update
-        cache = dict(cache)
-        if cfg.kv_cache_bits == 8:
-            kq, ks = _quant_kv(k_new)
-            vq, vs = _quant_kv(v_new)
-            cache["k"] = upd(cache["k"], kq, slot)
-            cache["v"] = upd(cache["v"], vq, slot)
-            cache["k_scale"] = upd(cache["k_scale"], ks, slot)
-            cache["v_scale"] = upd(cache["v_scale"], vs, slot)
-        else:
-            cache["k"] = upd(cache["k"], k_new, slot)
-            cache["v"] = upd(cache["v"], v_new, slot)
-        cache["slot_pos"] = _write_slot_pos(cache["slot_pos"], pos, slot)
-        return cache
+    slot = pos % cache["k"].shape[2]
+    new = {"k": k_new, "v": v_new}                     # [B, 1, ...]
     if cfg.kv_cache_bits == 8:
-        kq, ks = _quant_kv(k_new)
-        vq, vs = _quant_kv(v_new)
-        cache = dict(cache)
-        cache["k"] = jax.lax.dynamic_update_slice_in_dim(cache["k"], kq, slot, 1)
-        cache["v"] = jax.lax.dynamic_update_slice_in_dim(cache["v"], vq, slot, 1)
-        cache["k_scale"] = jax.lax.dynamic_update_slice_in_dim(cache["k_scale"], ks, slot, 1)
-        cache["v_scale"] = jax.lax.dynamic_update_slice_in_dim(cache["v_scale"], vs, slot, 1)
-    else:
-        cache = dict(cache)
-        cache["k"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), slot, 1)
-        cache["v"] = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), slot, 1)
-    cache["slot_pos"] = _write_slot_pos(cache["slot_pos"], pos, slot)
+        new["k"], new["k_scale"] = _quant_kv(k_new)
+        new["v"], new["v_scale"] = _quant_kv(v_new)
+    cache = dict(cache)
+    if cfg.mask_cache_update:
+        upd = _row_update if pos.ndim == 1 else _mask_update
+        old = layer_cache(cache, layer)
+        written = {n: upd(old[n], a, slot) for n, a in new.items()}
+        written["slot_pos"] = _write_slot_pos(old["slot_pos"], pos, slot)
+        for n, a in written.items():
+            cache[n] = jax.lax.dynamic_update_index_in_dim(cache[n], a,
+                                                           layer, 0)
+        return cache
+    b = k_new.shape[0]
+    rows = jnp.arange(b)
+    slots = jnp.broadcast_to(slot, (b,))
+    new = {n: a[:, 0] for n, a in new.items()}
+    new["slot_pos"] = jnp.broadcast_to(pos, (b,))
+    for n, a in new.items():
+        cache[n] = cache[n].at[layer, rows, slots].set(
+            a.astype(cache[n].dtype), indices_are_sorted=True,
+            unique_indices=True)
     return cache
 
 
@@ -435,41 +437,52 @@ def _valid_slots(cache: dict, cfg: AttnConfig, pos):
 
 
 def decode_attend(q, cache: dict, cfg: AttnConfig, pos):
-    """q: [B, 1, Hq, D] against the cache; returns [B, 1, Hq, D].
+    """q: [B, 1, Hq, D] against one layer's cache; returns [B, 1, Hq, D].
 
-    The softmax reductions run over the (possibly "sp"-sharded) cache seq
+    The queries are grouped [B, G, R, D] over the G stored KV heads
+    (R = Hq / G), so K and V are read as stored ([B, S, G, D]): no head
+    repeat, no f32 or transposed copy of the cache. Products, sums,
+    softmax and PV are f32 (a bf16 cache upcasts exactly); both
+    contractions are multiply-and-sum, so XLA reduces them in fusions
+    that read the cache and never rounds q to bf16 for the MXU. The
+    softmax reductions run over the (possibly "sp"-sharded) cache seq
     axis; GSPMD lowers them to partial reductions + all-reduce — the
     flash-decoding merge.
     """
-    b, _, hq, d = q.shape
     if cfg.attn_int8 and cfg.kv_cache_bits == 8:
         return _decode_attend_gqa_int8(q, cache, cfg, pos)
+    b, _, hq, d = q.shape
+    g = cfg.n_kv_heads
+    r = hq // g
     k, v = cache["k"], cache["v"]
     if cfg.kv_cache_bits == 8:
         k = k.astype(jnp.float32) * cache["k_scale"][..., None]
         v = v.astype(jnp.float32) * cache["v_scale"][..., None]
-    n_rep = hq // cfg.n_kv_heads
-    if cfg.gqa_decode:
-        return _decode_attend_gqa(q, k, v, cache, cfg, pos)
-    kh = _repeat_kv(k, n_rep).transpose(0, 2, 1, 3)    # [B, Hq, S, D]
-    vh = _repeat_kv(v, n_rep).transpose(0, 2, 1, 3)
     if cfg.decode_pin_seq:
-        # Flash-decoding sharding: WITHOUT the pin GSPMD prefers head-
-        # sharded kh/vh and re-shards (replicates!) the whole seq-sharded
-        # cache every step — the dominant decode HBM/collective cost in
-        # the baseline cells. Pinning keeps the contraction seq-local;
-        # only the [B,H,1] partial-softmax stats cross devices.
-        kh = constraint(kh, PS("dp", None, "sp", None))
-        vh = constraint(vh, PS("dp", None, "sp", None))
-    qt = q.transpose(0, 2, 1, 3).astype(jnp.float32) * d ** -0.5
-    logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kh.astype(jnp.float32))
+        # Flash-decoding sharding: without the pin GSPMD may prefer a
+        # head-sharded read and replicate the whole seq-sharded cache
+        # every step. Pinned, the contractions stay seq-local; only the
+        # partial-softmax stats cross devices.
+        k = constraint(k, PS("dp", "sp", None, None))
+        v = constraint(v, PS("dp", "sp", None, None))
+    qg = q.reshape(b, 1, g, r, d).astype(jnp.float32) * d ** -0.5
+    kf, vf = k.astype(jnp.float32), v.astype(jnp.float32)
+    # One multiply-and-sum per query head of a group: each reads K as
+    # stored and upcasts it inside its reduction. One sum over K
+    # broadcast across the R heads makes XLA write the f32 K out first.
+    # One variadic reduce of all R reads K once, but on a TPU v5e it
+    # compiled ~10x slower and the qwen3-1.7b chat step took 112 ms
+    # against 58 ms with these sums.
+    logits = jnp.stack([jnp.sum(qg[:, :, :, i] * kf, axis=-1)
+                        for i in range(r)], axis=-1)   # [B, S, G, R]
     if cfg.decode_pin_seq:
-        logits = constraint(logits, PS("dp", None, None, "sp"))
+        logits = constraint(logits, PS("dp", "sp", None, None))
     valid = _valid_slots(cache, cfg, pos)
-    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
-    p_ = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhqk,bhkd->bhqd", p_, vh.astype(jnp.float32))
-    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+    logits = jnp.where(valid[:, :, None, None], logits, NEG_INF)
+    p_ = jax.nn.softmax(logits, axis=1)
+    out = jnp.stack([jnp.sum(p_[:, :, :, i, None] * vf, axis=1)
+                     for i in range(r)], axis=2)       # [B, G, R, D]
+    return out.reshape(b, 1, hq, d).astype(q.dtype)
 
 
 def _decode_attend_gqa_int8(q, cache, cfg: AttnConfig, pos):
@@ -511,31 +524,6 @@ def _decode_attend_gqa_int8(q, cache, cfg: AttnConfig, pos):
         pi, vq, (((3,), (2,)), ((0, 1), (0, 1))),
         preferred_element_type=jnp.int32)              # [B,G,R,D]
     out = out_i.astype(jnp.float32) * p_scale
-    return out.reshape(b, 1, hq, d).astype(q.dtype)
-
-
-def _decode_attend_gqa(q, k, v, cache, cfg: AttnConfig, pos):
-    """Grouped decode attention: queries reshaped [B, G, R, D] against the
-    UN-REPEATED [B, G, S, D] cache. _repeat_kv would materialize the cache
-    R times per step — at 405B-decode scale that repeat IS the memory
-    bound (x16 the cache bytes). Numerically identical to the repeat path.
-    """
-    b, _, hq, d = q.shape
-    g = cfg.n_kv_heads
-    r = hq // g
-    kt = k.transpose(0, 2, 1, 3)                       # [B, G, S, D]
-    vt = v.transpose(0, 2, 1, 3)
-    if cfg.decode_pin_seq:
-        kt = constraint(kt, PS("dp", None, "sp", None))
-        vt = constraint(vt, PS("dp", None, "sp", None))
-    qt = q.reshape(b, g, r, d).astype(jnp.float32) * d ** -0.5
-    logits = jnp.einsum("bgrd,bgsd->bgrs", qt, kt.astype(jnp.float32))
-    if cfg.decode_pin_seq:
-        logits = constraint(logits, PS("dp", None, None, "sp"))
-    valid = _valid_slots(cache, cfg, pos)
-    logits = jnp.where(valid[:, None, None, :], logits, NEG_INF)
-    p_ = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bgrs,bgsd->bgrd", p_, vt.astype(jnp.float32))
     return out.reshape(b, 1, hq, d).astype(q.dtype)
 
 
@@ -601,12 +589,14 @@ def apply_prefill(p, cfg: AttnConfig, x, positions, exec_cfg, cache):
     return L.linear_apply(p["wo"], out, exec_cfg, "attn_o"), cache
 
 
-def apply_decode(p, cfg: AttnConfig, x, pos, exec_cfg, cache):
+def apply_decode(p, cfg: AttnConfig, x, pos, exec_cfg, cache, layer):
     """One-token decode. x: [B, 1, d]. Returns (out [B,1,d], cache).
 
-    ``pos`` is a scalar (whole batch at one position) or a [B] vector
-    (continuous batching: per-row positions for rope, cache write, and
-    causal masking)."""
+    ``cache`` is the stacked cache of every layer at this pattern
+    position (leaves [L, B, ...]); this layer is ``layer``. ``pos`` is a
+    scalar (whole batch at one position) or a [B] vector (continuous
+    batching: per-row positions for rope, cache write, and causal
+    masking)."""
     pos = jnp.asarray(pos, jnp.int32)
     # [1] or [B,1]: both broadcast per-row in rope
     positions = pos[None] if pos.ndim == 0 else pos[:, None]
@@ -616,7 +606,7 @@ def apply_decode(p, cfg: AttnConfig, x, pos, exec_cfg, cache):
         # cross KV precomputed at prefill and held in the cache
         if cfg.qk_norm:
             q = L.rms_norm(q, p["qnorm"]["g"])
-        out = decode_attend(q, cache, cfg, pos)
+        out = decode_attend(q, layer_cache(cache, layer), cfg, pos)
         out = out.reshape(*x.shape[:-1], cfg.n_heads * cfg.d_head)
         return L.linear_apply(p["wo"], out, exec_cfg, "attn_o"), cache
     k = L.linear_apply(p["wk"], x, exec_cfg, "attn_k")
@@ -628,8 +618,8 @@ def apply_decode(p, cfg: AttnConfig, x, pos, exec_cfg, cache):
         k = L.rms_norm(k, p["knorm"]["g"])
     q = L.rope(q, positions, cfg.rope_theta)
     k = L.rope(k, positions, cfg.rope_theta)
-    cache = cache_update(cache, cfg, k, v, pos)
-    out = decode_attend(q, cache, cfg, pos)
+    cache = cache_update(cache, cfg, k, v, pos, layer)
+    out = decode_attend(q, layer_cache(cache, layer), cfg, pos)
     out = out.reshape(*x.shape[:-1], cfg.n_heads * cfg.d_head)
     return L.linear_apply(p["wo"], out, exec_cfg, "attn_o"), cache
 

@@ -179,25 +179,32 @@ def decode_step(params, cfg: T.ModelConfig, token, pos, cache, exec_cfg):
     or a [B] int32 vector of per-row positions (continuous batching —
     each row ropes, writes its cache slot, and masks at its own pos).
 
+    The stacked cache is the layer loop's carry, not a scanned input
+    rebuilt as an output stack: each layer writes its new token into it
+    and reads its K/V from it in place, so a donated cache buffer is
+    updated where it lies. Only the parameters are scanned.
+
     Returns (logits [B, V], new_cache)."""
     x = L.embed_apply(params["embed"], token[:, None]).astype(jnp.bfloat16)
     x = constraint(x, PS("dp", None, None))
 
-    def group_body(x, xs):
-        group_params, group_cache = xs
-        new_caches = {}
+    def group_body(carry, xs):
+        x, cache = carry
+        group_params, g = xs
+        cache = dict(cache)
         for i, spec in enumerate(cfg.pattern):
-            x, c_new = T.block_apply_decode(group_params[f"p{i}"], cfg, spec,
-                                            x, pos, exec_cfg,
-                                            group_cache[f"p{i}"])
-            new_caches[f"p{i}"] = c_new
-        return x, new_caches
+            x, cache[f"p{i}"] = T.block_apply_decode(
+                group_params[f"p{i}"], cfg, spec, x, pos, exec_cfg,
+                cache[f"p{i}"], g)
+        return (x, cache), None
 
-    x, caches = jax.lax.scan(group_body, x, (params["blocks"], cache))
+    (x, cache), _ = jax.lax.scan(
+        group_body, (x, cache),
+        (params["blocks"], jnp.arange(cfg.n_groups, dtype=jnp.int32)))
     x = L.rms_norm(x, params["final_norm"]["g"])
     logits = L.linear_apply(params["head"], x[:, 0], exec_cfg, "lm_head")
     logits = constraint(logits, PS("dp", "tp"))
-    return logits, caches
+    return logits, cache
 
 
 # ---------------------------------------------------------------------------

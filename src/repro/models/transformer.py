@@ -56,7 +56,6 @@ class ModelConfig:
     flash_vjp: bool = False      # memory-efficient attention backward
     kv_col_parallel: bool = False  # kv projections column-parallel (§Perf)
     decode_pin_seq: bool = False   # pin decode cache seq-sharding (§Perf)
-    gqa_decode: bool = False       # grouped decode einsum, no KV repeat
     mask_cache_update: bool = False  # shard-local where() cache writes
     kv_replicated: bool = False    # kv projections replicated over tp
     attn_int8: bool = False        # integer decode attention on int8 cache
@@ -83,7 +82,7 @@ class ModelConfig:
             window=spec.window, cross=(spec.kind == "cross"),
             kv_cache_bits=self.kv_cache_bits, flash_vjp=self.flash_vjp,
             kv_col_parallel=self.kv_col_parallel,
-            decode_pin_seq=self.decode_pin_seq, gqa_decode=self.gqa_decode,
+            decode_pin_seq=self.decode_pin_seq,
             mask_cache_update=self.mask_cache_update,
             kv_replicated=self.kv_replicated, attn_int8=self.attn_int8,
             block=self.attn_block)
@@ -161,13 +160,19 @@ def block_apply_train(p, cfg: ModelConfig, spec: LayerSpec, x, positions,
 
 
 def block_apply_decode(p, cfg: ModelConfig, spec: LayerSpec, x, pos,
-                       exec_cfg, cache):
+                       exec_cfg, cache, layer):
+    """``cache``: this pattern position's stacked cache (leaves [L, ...]),
+    of which the block reads and writes layer ``layer``."""
     h = L.rms_norm(x, p["ln1"]["g"])
     if spec.kind == "mamba":
-        mix, cache = ssm_mod.apply_decode(p["mix"], cfg.ssm, h, exec_cfg, cache)
+        mix, c = ssm_mod.apply_decode(p["mix"], cfg.ssm, h, exec_cfg,
+                                      attn.layer_cache(cache, layer))
+        cache = jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+            cache, c)
     else:
         mix, cache = attn.apply_decode(p["mix"], cfg.attn_cfg(spec), h, pos,
-                                       exec_cfg, cache)
+                                       exec_cfg, cache, layer)
     x = x + mix
     if spec.ffn != "none":
         h = L.rms_norm(x, p["ln2"]["g"])

@@ -55,67 +55,133 @@ def test_flash_vjp_matches_autodiff(causal, window):
         np.testing.assert_allclose(np.asarray(b), np.asarray(a), atol=3e-5)
 
 
-def _mk_attn_cfg(**kw):
-    return A.AttnConfig(d_model=64, n_heads=8, n_kv_heads=2, d_head=16,
-                        **kw)
+def _mk_attn_cfg(n_kv_heads=2, **kw):
+    return A.AttnConfig(d_model=64, n_heads=8, n_kv_heads=n_kv_heads,
+                        d_head=16, **kw)
 
 
-def _random_cache(cfg, b, s_cache, n_filled, seed=0):
+def _stacked(cache, n_layers=1):
+    """A one-layer cache stacked as decode carries it, [L, B, ...]."""
+    return jax.tree.map(lambda a: jnp.stack([a] * n_layers), cache)
+
+
+def _repeat_decode_ref(q, cache, cfg, pos):
+    """Decode attention as it was before the grouped read: K and V
+    repeated to every query head, upcast to f32, transposed [B, H, S, D];
+    the mask written out from slot_pos."""
+    b, _, hq, d = q.shape
+    n_rep = hq // cfg.n_kv_heads
+    kh = jnp.repeat(cache["k"], n_rep, axis=2).transpose(0, 2, 1, 3)
+    vh = jnp.repeat(cache["v"], n_rep, axis=2).transpose(0, 2, 1, 3)
+    qt = q.transpose(0, 2, 1, 3).astype(jnp.float32) * d ** -0.5
+    hi = jax.lax.Precision.HIGHEST
+    logits = jnp.einsum("bhqd,bhkd->bhqk", qt, kh.astype(jnp.float32),
+                        precision=hi)
+    sp = jnp.broadcast_to(cache["slot_pos"], (b, cache["k"].shape[1]))
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))[:, None]
+    valid = (sp >= 0) & (sp <= pos_b)
+    if cfg.window is not None:
+        valid &= sp > pos_b - cfg.window
+    logits = jnp.where(valid[:, None, None, :], logits, -1e30)
+    p_ = jax.nn.softmax(logits, axis=-1)
+    out = jnp.einsum("bhqk,bhkd->bhqd", p_, vh.astype(jnp.float32),
+                     precision=hi)
+    return out.transpose(0, 2, 1, 3).astype(q.dtype)
+
+
+def _filled_cache(cfg, pos, s_cache, seed):
+    """Random bf16 K/V; row b holds positions 0..pos[b] in ring slots."""
     rng = np.random.default_rng(seed)
+    b = len(pos)
     cache = A.init_cache(cfg, b, s_cache)
-    k = jnp.asarray(rng.normal(size=cache["k"].shape), jnp.bfloat16)
-    v = jnp.asarray(rng.normal(size=cache["v"].shape), jnp.bfloat16)
-    slot = jnp.where(jnp.arange(s_cache) < n_filled,
-                     jnp.arange(s_cache), -1).astype(jnp.int32)
-    return {"k": k, "v": v, "slot_pos": slot}
+    sp = np.full((b, cache["k"].shape[1]), -1, np.int32)
+    for r, p in enumerate(pos):
+        for t in range(max(0, p + 1 - sp.shape[1]), p + 1):
+            sp[r, t % sp.shape[1]] = t
+    return {"k": jnp.asarray(rng.normal(size=cache["k"].shape), jnp.bfloat16),
+            "v": jnp.asarray(rng.normal(size=cache["v"].shape), jnp.bfloat16),
+            "slot_pos": jnp.asarray(sp)}
 
 
-def test_gqa_decode_equals_repeat_decode():
-    """The grouped (no-repeat) decode attention == the repeat path."""
-    base = _mk_attn_cfg()
-    gqa = _mk_attn_cfg(gqa_decode=True)
-    cache = _random_cache(base, b=3, s_cache=64, n_filled=40)
+@pytest.mark.parametrize("window", [None, 16], ids=["full", "ring"])
+@pytest.mark.parametrize("per_row", [True, False], ids=["per_row", "scalar"])
+@pytest.mark.parametrize("n_kv_heads", [8, 4], ids=["R1", "R2"])
+def test_grouped_decode_equals_repeat_decode(window, per_row, n_kv_heads):
+    """The grouped read of the stored cache == the repeat-and-f32 math,
+    per-row and scalar pos, full and sliding-window ring caches, R = 1
+    (plain MHA) and 2. On the sliding-window ring the rows past 16
+    positions have wrapped, so their valid slots are out of order."""
+    cfg = _mk_attn_cfg(n_kv_heads=n_kv_heads, window=window)
+    pos = [39, 5, 63, 20] if per_row else [39] * 4
+    cache = _filled_cache(cfg, pos, 64, seed=sum(pos) + n_kv_heads)
     rng = np.random.default_rng(1)
-    q = jnp.asarray(rng.normal(size=(3, 1, 8, 16)), jnp.bfloat16)
-    pos = jnp.asarray(39, jnp.int32)
-    o1 = A.decode_attend(q, cache, base, pos)
-    o2 = A.decode_attend(q, cache, gqa, pos)
-    np.testing.assert_allclose(np.asarray(o2, np.float32),
-                               np.asarray(o1, np.float32), atol=2e-2)
+    q = jnp.asarray(rng.normal(size=(4, 1, 8, 16)), jnp.bfloat16)
+    p = jnp.asarray(pos if per_row else pos[0], jnp.int32)
+    got = jax.jit(A.decode_attend, static_argnums=2)(q, cache, cfg, p)
+    want = _repeat_decode_ref(q, cache, cfg, p)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+    # the same f32 math before the final bf16 rounding
+    got32 = A.decode_attend(q.astype(jnp.float32), cache, cfg, p)
+    want32 = _repeat_decode_ref(q.astype(jnp.float32), cache, cfg, p)
+    np.testing.assert_allclose(np.asarray(got32), np.asarray(want32),
+                               rtol=1e-5, atol=1e-6)
 
 
-def test_gqa_decode_windowed():
-    base = _mk_attn_cfg(window=16)
-    gqa = _mk_attn_cfg(window=16, gqa_decode=True)
-    # SWA ring cache of size 16; slot i holds absolute position 16 + i
-    rng0 = np.random.default_rng(5)
-    cache = A.init_cache(base, 2, 64)
-    cache = {"k": jnp.asarray(rng0.normal(size=cache["k"].shape), jnp.bfloat16),
-             "v": jnp.asarray(rng0.normal(size=cache["v"].shape), jnp.bfloat16),
-             "slot_pos": (jnp.arange(16) + 16).astype(jnp.int32)}
-    rng = np.random.default_rng(2)
-    q = jnp.asarray(rng.normal(size=(2, 1, 8, 16)), jnp.bfloat16)
-    pos = jnp.asarray(31, jnp.int32)
-    np.testing.assert_allclose(
-        np.asarray(A.decode_attend(q, cache, gqa, pos), np.float32),
-        np.asarray(A.decode_attend(q, cache, base, pos), np.float32),
-        atol=2e-2)
+def _row_write_ref(cache, k_new, v_new, pos):
+    """The where() write over a whole one-layer cache, as decode did it:
+    each row's token at its own slot pos % S."""
+    b, s_cache = cache["slot_pos"].shape
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
+    hit = jnp.arange(s_cache)[None, :] == (pos % s_cache)[:, None]
+    out = dict(cache)
+    for n, new in (("k", k_new), ("v", v_new)):
+        out[n] = jnp.where(hit[:, :, None, None], new.astype(out[n].dtype),
+                           out[n])
+    out["slot_pos"] = jnp.where(hit, pos[:, None], out["slot_pos"])
+    return out
+
+
+@pytest.mark.parametrize("window", [None, 8], ids=["full", "ring"])
+@pytest.mark.parametrize("per_row", [True, False], ids=["per_row", "scalar"])
+def test_in_place_write_equals_row_write(window, per_row):
+    """The scatter into one layer of the stacked cache writes exactly the
+    old where() write's bytes there and leaves the other layers alone,
+    through a ring that wraps."""
+    cfg = _mk_attn_cfg(window=window)
+    rng = np.random.default_rng(6)
+    one = A.init_cache(cfg, 3, 32)
+    stack = _stacked(one, 3)
+    for step in range(13):
+        kn = jnp.asarray(rng.normal(size=(3, 1, 2, 16)), jnp.bfloat16)
+        vn = jnp.asarray(rng.normal(size=(3, 1, 2, 16)), jnp.bfloat16)
+        pos = (jnp.asarray([step, step + 5, 2 * step], jnp.int32) if per_row
+               else jnp.asarray(step, jnp.int32))
+        one = _row_write_ref(one, kn, vn, pos)
+        stack = A.cache_update(stack, cfg, kn, vn, pos, 1)
+    for key in one:
+        np.testing.assert_array_equal(np.asarray(stack[key][1]),
+                                      np.asarray(one[key]), err_msg=key)
+        for other in (0, 2):
+            np.testing.assert_array_equal(
+                np.asarray(stack[key][other]),
+                np.asarray(A.init_cache(cfg, 3, 32)[key]), err_msg=key)
 
 
 @pytest.mark.parametrize("kv_bits", [16, 8])
 def test_mask_cache_update_equals_dus(kv_bits):
-    """where()-based cache writes == dynamic_update_slice writes."""
+    """where()-based cache writes == the in-place scatter writes."""
     base = _mk_attn_cfg(kv_cache_bits=kv_bits)
     masked = _mk_attn_cfg(kv_cache_bits=kv_bits, mask_cache_update=True)
     rng = np.random.default_rng(3)
-    c1 = A.init_cache(base, 2, 32)
+    c1 = _stacked(A.init_cache(base, 2, 32), 2)
     c2 = jax.tree.map(lambda x: x, c1)
     for step in range(5):
         kn = jnp.asarray(rng.normal(size=(2, 1, 2, 16)), jnp.bfloat16)
         vn = jnp.asarray(rng.normal(size=(2, 1, 2, 16)), jnp.bfloat16)
         pos = jnp.asarray(step, jnp.int32)
-        c1 = A.cache_update(c1, base, kn, vn, pos)
-        c2 = A.cache_update(c2, masked, kn, vn, pos)
+        c1 = A.cache_update(c1, base, kn, vn, pos, 1)
+        c2 = A.cache_update(c2, masked, kn, vn, pos, 1)
     for key in c1:
         np.testing.assert_allclose(
             np.asarray(c1[key], np.float32), np.asarray(c2[key], np.float32),
@@ -123,18 +189,19 @@ def test_mask_cache_update_equals_dus(kv_bits):
 
 
 def test_ring_cache_mask_update_wraps():
-    """SWA ring cache: mask update wraps at window size like the DUS path."""
+    """SWA ring cache: mask update wraps at window size like the scatter."""
     base = _mk_attn_cfg(window=8)
     masked = _mk_attn_cfg(window=8, mask_cache_update=True)
     rng = np.random.default_rng(4)
-    c1 = A.init_cache(base, 1, 64)
+    c1 = _stacked(A.init_cache(base, 1, 64))
     c2 = jax.tree.map(lambda x: x, c1)
-    assert c1["k"].shape[1] == 8   # ring sized to the window
+    assert c1["k"].shape[2] == 8   # ring sized to the window
     for step in range(13):         # wraps past the ring boundary
         kn = jnp.asarray(rng.normal(size=(1, 1, 2, 16)), jnp.bfloat16)
         vn = jnp.asarray(rng.normal(size=(1, 1, 2, 16)), jnp.bfloat16)
-        c1 = A.cache_update(c1, base, kn, vn, jnp.asarray(step, jnp.int32))
-        c2 = A.cache_update(c2, masked, kn, vn, jnp.asarray(step, jnp.int32))
+        c1 = A.cache_update(c1, base, kn, vn, jnp.asarray(step, jnp.int32), 0)
+        c2 = A.cache_update(c2, masked, kn, vn, jnp.asarray(step, jnp.int32),
+                            0)
     np.testing.assert_array_equal(np.asarray(c1["slot_pos"]),
                                   np.asarray(c2["slot_pos"]))
     np.testing.assert_allclose(np.asarray(c1["k"], np.float32),

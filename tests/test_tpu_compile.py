@@ -194,3 +194,79 @@ def test_classify_names_convs_by_layer(one_chip):
     assert sorted(_custom_calls(text, "bitserial_conv")) == [
         "bitserial_conv_conv1", "bitserial_conv_conv2"]
     assert _custom_calls(text, "bitserial_matmul") == ["bitserial_matmul_fc0"]
+
+
+# -- the decode step keeps the KV cache in place ------------------------------
+
+def _hlo_computations(text: str) -> dict:
+    """Instruction lines of each computation of a compiled HLO module."""
+    comps, lines = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) .*\{\s*$", line)
+        if head:
+            lines = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            lines = None
+        elif lines is not None:
+            lines.append(line)
+    return comps
+
+
+def _shapes_of(hlo_type: str):
+    for dtype, dims in re.findall(r"\b([a-z]+\d*)\[([\d,]*)\]", hlo_type):
+        n = 1
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        yield dtype, n
+
+
+def test_decode_step_updates_cache_in_place(one_chip):
+    """The full-width qwen3-1.7b decode step of the chat cell (12 slots x
+    3072, per-row pos, packed 8/8 on pallas_tpu): outside the parameters
+    and the layer loop, the only instructions whose output is as large as
+    one layer's K are the in-place K and V writes into the carried cache,
+    and nothing that large is f32 — no repeated, upcast, transposed or
+    restacked copy of the cache, in the loop or after it."""
+    from repro import configs
+    from repro.api.plan import build_plan
+    from repro.api.session import _jit_lm
+    from repro.core.policy import uniform_policy
+    from repro.launch import shapes
+    from repro.models import model as M
+    slots, seq = 12, 3072
+    cfg = configs.get("qwen3-1.7b")
+    pol = uniform_policy(8, 8)
+    plan = build_plan(cfg, pol, "serve_packed", "pallas_tpu")
+    params, _ = shapes.param_structs(cfg, serving_mode="serve_packed",
+                                     policy=pol)
+    cache = jax.eval_shape(lambda: M.init_cache(cfg, slots, seq))
+    rows = jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip)
+    decode = _jit_lm(cfg, plan, None, None, None)[1]
+    text = decode.lower(_on(one_chip, params), rows, rows,
+                        _on(one_chip, cache)).compile().as_text()
+
+    layer_k = slots * seq * cfg.n_kv_heads * cfg.d_head
+    comps = _hlo_computations(text)
+    called = {c for lines in comps.values() for line in lines
+              for c in re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", line)}
+    writes, big = [], []
+    for name, lines in comps.items():
+        if name in called:
+            continue      # a fusion's body: its values never reach HBM
+        for line in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+            if m is None:
+                continue
+            inst, hlo_type, op = m.groups()
+            sizes = [(dt, n) for dt, n in _shapes_of(hlo_type) if n >= layer_k]
+            if not sizes:
+                continue
+            assert not [dt for dt, _ in sizes if dt == "f32"], line[:300]
+            if op in ("parameter", "get-tuple-element", "tuple", "bitcast",
+                      "while"):
+                continue
+            in_place = ('"aliasing_operands":{"lists":[{' in line
+                        and "scatter" in line) or op == "scatter"
+            (writes if in_place else big).append(inst)
+    assert not big, big
+    assert len(writes) == 2, writes       # K and V, each once per layer
