@@ -3,9 +3,14 @@
 Set-up makes the weights and ``distinct_batches`` image batches on the
 device from the seed, compiles the configuration's route with
 ``loom.compile`` and warms ``ServingSession.classify`` at the mix's
-batch. The window calls ``classify`` back to back, each call ending in
-``block_until_ready``, cycling over the image batches. The window closes
-with the first call that ends after ``seconds``.
+batch, then times a few calls to learn how many make the mix's
+``ahead_s`` seconds of device work. The window dispatches ``classify``
+back to back, cycling over the image batches, and keeps that many calls
+in flight: before it dispatches one more it waits for the oldest, so a
+host that stands still for less than ``ahead_s`` leaves the chip fed.
+When ``seconds`` are up it dispatches nothing more, waits for every call
+it dispatched, and reads the clock after that wait: every call counts,
+over all of that time.
 
 A reservoir of ``compare_calls`` calls, drawn from the seed, keeps its
 logits; once the program's state is freed, the float32 reference
@@ -15,7 +20,9 @@ configuration's limit (``checks.py``).
 """
 from __future__ import annotations
 
+import collections
 import gc
+import math
 import time
 
 import numpy as np
@@ -23,6 +30,8 @@ import numpy as np
 import checks as checks_mod
 import manifest
 import trace_reduce
+
+TIMED_CALLS = 3                 # set-up calls timed to size the calls in flight
 
 
 def program_config(c: dict):
@@ -70,6 +79,12 @@ def run(cell, seed: int, seconds: float, trace_dir: str | None, *,
     t = time.perf_counter()
     jax.block_until_ready(sess.classify(xs[0]))
     log(f"warm-up {time.perf_counter() - t:.3f} s")
+    t = time.perf_counter()
+    for i in range(TIMED_CALLS):
+        jax.block_until_ready(sess.classify(xs[i % n_inputs]))
+    call_s = (time.perf_counter() - t) / TIMED_CALLS
+    depth = max(1, math.ceil(float(mix["ahead_s"]) / call_s))
+    log(f"one call {call_s * 1e3:.3f} ms: {depth} calls in flight")
     setup_s = time.perf_counter() - t_start
 
     compiles = trace_reduce.count_compiles()
@@ -77,26 +92,32 @@ def run(cell, seed: int, seconds: float, trace_dir: str | None, *,
     rng = np.random.default_rng([seed, 3])
     k = int(mix["compare_calls"])
     kept: list[tuple[int, int, object]] = []      # (call, input, logits)
-    calls: list[tuple[float, float]] = []
-    memory = 0                  # most bytes in use at a call's end
+    in_flight: collections.deque = collections.deque()
+    n = 0
+    memory = 0                  # most bytes in use after a wait
     if traced:
         trace_reduce.start(trace_dir)
     t_w0 = time.perf_counter()
     with trace_reduce.span("bench.window", traced):
-        while not calls or calls[-1][1] < t_w0 + seconds:
-            i = len(calls)
-            t0 = time.perf_counter()
+        while n == 0 or time.perf_counter() < t_w0 + seconds:
+            if len(in_flight) == depth:
+                with trace_reduce.span("bench.wait", traced):
+                    jax.block_until_ready(in_flight.popleft())
+                memory = max(memory, checks_mod.bytes_in_use())
             with trace_reduce.span("bench.classify", traced):
-                out = jax.block_until_ready(sess.classify(xs[i % n_inputs]))
-            calls.append((t0, time.perf_counter()))
-            memory = max(memory, checks_mod.bytes_in_use())
+                out = sess.classify(xs[n % n_inputs])
+            in_flight.append(out)
             # reservoir sampling: every call is kept with equal chance
-            if i < k:
-                kept.append((i, i % n_inputs, out))
+            if n < k:
+                kept.append((n, n % n_inputs, out))
             else:
-                j = int(rng.integers(0, i + 1))
+                j = int(rng.integers(0, n + 1))
                 if j < k:
-                    kept[j] = (i, i % n_inputs, out)
+                    kept[j] = (n, n % n_inputs, out)
+            n += 1
+        with trace_reduce.span("bench.wait", traced):
+            jax.block_until_ready(list(in_flight))
+        memory = max(memory, checks_mod.bytes_in_use())
     t_w1 = time.perf_counter()
     if traced:
         jax.profiler.stop_trace()
@@ -104,7 +125,7 @@ def run(cell, seed: int, seconds: float, trace_dir: str | None, *,
     log(f"bytes in use in the window {memory}, process peak "
         f"{checks_mod.process_peak_bytes()}")
     got = [(inp, np.asarray(out)) for _, inp, out in kept]
-    del sess, kept, out
+    del sess, kept, out, in_flight
     gc.collect()
 
     t = time.perf_counter()
@@ -120,9 +141,9 @@ def run(cell, seed: int, seconds: float, trace_dir: str | None, *,
     checks = checks_of(c, err)
     return {
         "kind": "cnn", "setup_s": setup_s, "t_w0": t_w0, "t_w1": t_w1,
-        "window_s": t_w1 - t_w0, "seconds": seconds, "calls": calls,
-        "batch": batch, "config": c, "traffic": mix,
-        "attempted": len(calls) * batch, "failed": 0,
+        "window_s": t_w1 - t_w0, "seconds": seconds, "calls": n,
+        "in_flight": depth, "batch": batch, "config": c, "traffic": mix,
+        "attempted": n * batch, "failed": 0,
         "memory_peak_bytes": memory, "compiles_in_window": n_compiles,
         "checks": checks, "correct": checks_mod.correct(checks),
         "control_checks": None if ctrl is None else checks_of(c, ctrl),

@@ -35,15 +35,12 @@ FIRST_TOKEN_WAIT_S = 60.0
 
 
 def program_config(c: dict, mix: dict):
-    """The program's own config for ``c``, checked against the file's
-    published sizes, with the KV length the mix asks for."""
+    """The program's own config for ``c``, checked against the fields
+    that the configuration's reference module says the file fixes
+    (``program_fields``), with the KV length the mix asks for."""
     from repro import configs
     pc = configs.get(c["program_config"], smoke=c.get("program_smoke", False))
-    want = {"n_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
-            "vocab": c["vocab_size"], "n_heads": c["num_attention_heads"],
-            "n_kv_heads": c["num_key_value_heads"], "d_head": c["head_dim"],
-            "d_ff": c["intermediate_size"], "rope_theta": c["rope_theta"],
-            "qk_norm": True, "activation": "silu"}
+    want = manifest.load_module("refs", c["reference"]).program_fields(c)
     got = {k: getattr(pc, k) for k in want}
     if got != want:
         raise ValueError(f"program config {c['program_config']} is {got}, "

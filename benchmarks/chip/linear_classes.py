@@ -3,33 +3,26 @@ kernels.
 
 The program names each bit-serial matmul kernel after its layer,
 ``bitserial_matmul_<layer>`` (``bitserial_matmul_attn_q.12`` in the
-trace), with the layer names ``work.lm_linears`` uses. A class is the
-layers whose names share a prefix. Its share of the roofline is the
-least time of its linears (``work.py``, each at its own bound, active
-rows only) over the window's decode steps, divided by the device time
-of its kernels inside the decode programs of the traced window; the
-classes together are ``bitserial_matmul_roofline.decode`` split up.
-A trace whose kernels carry no layer names gives None.
+trace), with the layer names of the reference module's
+``decode_linears``. A class is the layers whose names share a prefix.
+Its share of the roofline is the least time of its linears (``work.py``,
+each at its own bound, active rows only) over the window's decode steps,
+divided by the device time of its kernels inside the decode programs of
+the traced window; the classes together are
+``bitserial_matmul_roofline.decode`` split up. A trace whose kernels
+carry no layer names gives None, and so does a configuration whose
+reference states no decode work.
 """
 from __future__ import annotations
 
 import readers
-import work
 
 CLASSES = {"attn": "attn_", "mlp": "ffn_", "lm_head": "lm_head"}
 
 
-def least_s(rec, pk, prefix: str) -> float:
+def least_s(rec, pk, prefix: str) -> float | None:
     """``readers.decode_least_s`` over the linears named ``prefix*``."""
-    c = rec["config"]
-    total = 0.0
-    for s in readers.window_steps(rec):
-        if s.contexts:
-            for name, k, n, count in work.lm_linears(c):
-                if name.startswith(prefix):
-                    total += count * work.least_time(
-                        *work.linear(len(s.contexts), k, n), pk)
-    return total
+    return readers.decode_least_s(rec, pk, prefix)
 
 
 def kernel_s(tr, prefix: str) -> float:
@@ -47,4 +40,5 @@ def decode_roofline(ctx, cls: str) -> float | None:
     spent = kernel_s(tr, prefix)
     if not spent:
         return None
-    return readers.pct_of(least_s(rec, ctx["peaks"], prefix), spent)
+    least = least_s(rec, ctx["peaks"], prefix)
+    return None if least is None else readers.pct_of(least, spent)

@@ -8,6 +8,28 @@ names its configuration (``configs/<name>.json``) and its traffic mix
 of its own in ``metrics/<name>.py``, whose unit, layer and ``moves`` only
 ``BENCHMARK.json`` states. Adding a cell, a mix or a metric
 therefore adds files and manifest entries and edits none.
+
+Whatever is particular to an architecture lives in its reference
+module, so that a configuration of another architecture comes in with a
+module of its own. A ``kind: lm`` reference gives, for a configuration
+file's contents ``c``:
+
+- ``make_weights(seed, c)``: every weight from the seed, on the device;
+- ``program_tree(weights) -> (params, specs)``: the same arrays in the
+  program's dense layout;
+- ``served_gaps(weights, c, prompt, served, pad_to, control=None)``: the
+  normalized gap of each served token against the float32 reference;
+- ``program_fields(c) -> dict``: each ``ModelConfig`` attribute that the
+  file fixes, with its value; ``drivers/lm.py`` refuses a program config
+  that differs in any of them;
+- ``decode_linears(c) -> [(name, K, N, count)]``: every linear of one
+  token's forward pass, named as the program names its kernels;
+- ``decode_attention(c, ctx) -> (ops, bytes)``: one row's decode
+  attention over ``ctx`` valid positions, all layers.
+
+The last two may be left out: the readers that count decode work from
+them (``decode_mfu_pct``, ``bitserial_matmul_roofline.*``) then read
+None in that configuration's cells, and it may bring readers of its own.
 """
 from __future__ import annotations
 

@@ -1,7 +1,8 @@
 """Share of the roofline of the fused bit-serial conv kernels: the least
 time of every conv of a classify call (each at its own bound, from
-work.py) times the calls of the traced window, divided by the device
-time of the ``bitserial_conv`` kernels in that window."""
+work.py) times the calls of the traced window (one ``bench.classify``
+span each), divided by the device time of the ``bitserial_conv`` kernels
+in that window."""
 import readers
 import work
 
@@ -11,7 +12,8 @@ def read(ctx):
     if tr is None or rec is None:
         return None
     spans = tr.host_spans("bench.classify")
-    kernel_s = sum(op.dur for op in tr.ops_in(spans, r"^bitserial_conv"))
+    kernel_s = sum(op.dur for op in tr.ops_in(
+        tr.host_spans("bench.window"), r"^bitserial_conv"))
     if not kernel_s or not spans:
         return None
     least = sum(work.least_time(*work.conv(rec["batch"], *layer[1:]),

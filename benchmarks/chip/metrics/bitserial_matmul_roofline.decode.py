@@ -2,7 +2,8 @@
 least time of every decode linear (each at its own bound, active rows
 only, from work.py) over the window's decode steps, divided by the
 device time of the ``bitserial_matmul`` kernels inside the decode
-programs of the traced window."""
+programs of the traced window. None where the configuration's reference
+states no decode work."""
 import readers
 
 
@@ -12,7 +13,7 @@ def read(ctx):
         return None
     runs = tr.programs("decode")
     kernel_s = sum(op.dur for op in tr.ops_in(runs, r"^bitserial_matmul"))
-    if not kernel_s:
+    least = readers.decode_least_s(rec, ctx["peaks"])
+    if not kernel_s or least is None:
         return None
-    return readers.pct_of(readers.decode_least_s(rec, ctx["peaks"]),
-                          kernel_s / 1e9)
+    return readers.pct_of(least, kernel_s / 1e9)

@@ -1,5 +1,6 @@
-"""Device time per classify call: the programs run inside each
-``bench.classify`` span of the traced window."""
+"""Device time per classify call: the device's busy time in the traced
+window over the calls dispatched in it (one ``bench.classify`` span
+each), every one of which ends inside the window."""
 
 def read(ctx):
     tr = ctx["trace"]
@@ -8,5 +9,4 @@ def read(ctx):
     spans = tr.host_spans("bench.classify")
     if not spans:
         return None
-    busy = sum(tr.device_busy_in(s.start, s.end) for s in spans)
-    return busy / len(spans) * 1e3
+    return tr.busy_s / len(spans) * 1e3

@@ -1,5 +1,5 @@
-"""Images classified in the window (each call ends in
-block_until_ready) over the window's seconds."""
+"""Images classified in the window over the window's seconds. The
+window closes once every call dispatched in it has ended."""
 import readers
 
 
@@ -7,4 +7,4 @@ def read(ctx):
     rec = readers.cnn(ctx)
     if rec is None:
         return None
-    return len(rec["calls"]) * rec["batch"] / rec["window_s"]
+    return rec["calls"] * rec["batch"] / rec["window_s"]

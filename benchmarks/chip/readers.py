@@ -44,14 +44,21 @@ def pct_of(part: float, whole: float) -> float | None:
     return 100.0 * part / whole if whole > 0 else None
 
 
-def decode_least_s(rec, pk) -> float:
-    """Least seconds of every decode linear of the window's steps, each
-    at its own bound, with the step's active rows."""
+def decode_least_s(rec, pk, prefix: str = "") -> float | None:
+    """Least seconds of every decode linear whose name starts with
+    ``prefix`` over the window's steps, each at its own bound, with the
+    step's active rows; None where the configuration's reference states
+    no decode work."""
     c = rec["config"]
+    ref = work.lm_decode(c)
+    if ref is None:
+        return None
+    linears = [(k, n, count) for name, k, n, count in ref.decode_linears(c)
+               if name.startswith(prefix)]
     total = 0.0
     for s in window_steps(rec):
         if s.contexts:
-            for _, k, n, count in work.lm_linears(c):
+            for k, n, count in linears:
                 total += count * work.least_time(
                     *work.linear(len(s.contexts), k, n), pk)
     return total

@@ -17,6 +17,11 @@ layers scanned so that only one layer is upcast at a time.
 Optional fake quantization (``a_bits``/``w_bits``) turns the reference
 into the lower-precision control: symmetric per-tensor weights and
 per-row activations at the given widths, on every linear.
+
+It also states what the harness reads of this architecture, by the
+contract in ``manifest.py``: the program config's fields that the file
+fixes (``program_fields``) and the decode work (``decode_linears``,
+``decode_attention``).
 """
 from __future__ import annotations
 
@@ -37,6 +42,41 @@ def sizes(cfg: dict) -> dict:
         "num_attention_heads", "num_key_value_heads", "head_dim",
         "vocab_size", "rope_theta", "rms_norm_eps")}
 
+
+# -- what the harness reads of this architecture (manifest.py) ---------------
+
+def program_fields(c: dict) -> dict:
+    """Each ``ModelConfig`` attribute that the configuration file fixes,
+    with its value."""
+    return {"n_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
+            "vocab": c["vocab_size"], "n_heads": c["num_attention_heads"],
+            "n_kv_heads": c["num_key_value_heads"], "d_head": c["head_dim"],
+            "d_ff": c["intermediate_size"], "rope_theta": c["rope_theta"],
+            "qk_norm": True, "activation": "silu"}
+
+
+def decode_linears(c: dict) -> list[tuple[str, int, int, int]]:
+    """(name, K, N, count) of every linear of one token's forward pass."""
+    d, f = c["hidden_size"], c["intermediate_size"]
+    h, kv, dh = (c["num_attention_heads"], c["num_key_value_heads"],
+                 c["head_dim"])
+    n = c["num_hidden_layers"]
+    return [("attn_q", d, h * dh, n), ("attn_k", d, kv * dh, n),
+            ("attn_v", d, kv * dh, n), ("attn_o", h * dh, d, n),
+            ("ffn_gate", d, f, n), ("ffn_up", d, f, n),
+            ("ffn_down", f, d, n), ("lm_head", d, c["vocab_size"], 1)]
+
+
+def decode_attention(c: dict, ctx: int, kv_bytes: int = 2):
+    """(ops, bytes) of one row's decode attention over ``ctx`` valid
+    positions, all layers."""
+    h, kv, dh = (c["num_attention_heads"], c["num_key_value_heads"],
+                 c["head_dim"])
+    n = c["num_hidden_layers"]
+    return n * 4 * ctx * h * dh, n * 2 * ctx * kv * dh * kv_bytes
+
+
+# -- seeded weights ------------------------------------------------------------
 
 def _shapes(c: dict) -> dict:
     d, f = c["hidden_size"], c["intermediate_size"]

@@ -67,7 +67,7 @@ def cnn_cell() -> manifest.Cell:
                   "a_bits": 8, "w_bits": 8},
         "limits": _limits("vgg16-cifar")}
     mix = {"kind": "cnn_closed_loop", "batch": 8, "distinct_batches": 2,
-           "compare_calls": 2}
+           "compare_calls": 2, "ahead_s": 0.25}
     return manifest.Cell("rehearsal-cnn", 1, config, mix, [], [])
 
 

@@ -32,6 +32,9 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
 TRACE_DIR = os.path.join(ROOT, ".bench_trace")
+# Programs that may wait on a chip at once. The runtime's default, 32,
+# cuts a driver that keeps seconds of calls in flight to about one.
+MAX_INFLIGHT = 256
 
 
 def log(msg: str) -> None:
@@ -61,6 +64,8 @@ def enable_compile_cache() -> None:
 def find_chips(need: int):
     """The TPU devices, or None when this machine has too few."""
     import jax
+    jax.config.update("jax_pjrt_client_create_options",
+                      {"max_inflight_computations": MAX_INFLIGHT})
     devs = jax.devices()
     if not devs or devs[0].platform != "tpu" or len(devs) < need:
         log(f"needs {need} TPU chip(s); JAX found "

@@ -140,3 +140,101 @@ def test_a_new_cell_and_metric_need_no_edit(tmp_path, monkeypatch):
         p["name"] for p in manifest.cell("vgg16-cifar-b256", m).per_layer]
     after = _hashes(bench)
     assert {k: v for k, v in after.items() if k in before} == before
+
+
+STUB_REF = '''"""Stand-in reference of a DeepSeekMoE decoder: the fields only."""
+
+
+def program_fields(c):
+    return {"n_layers": c["num_hidden_layers"], "d_model": c["hidden_size"],
+            "vocab": c["vocab_size"], "n_heads": c["num_attention_heads"],
+            "n_kv_heads": c["num_key_value_heads"],
+            "d_head": c["hidden_size"] // c["num_attention_heads"],
+            "d_ff": c["intermediate_size"], "rope_theta": c["rope_theta"],
+            "qk_norm": c["qk_norm"], "activation": "silu", "family": "moe"}
+'''
+
+
+def _decode_trace():
+    """A traced window of 0-10 s: one decode program holding a named
+    kernel of each class."""
+    from trace_reduce import Event, Reduced
+    ms = 1e6
+    ops = [Event(f"%bitserial_matmul_{c}.{i} = s32[8,128]{{1,0}} "
+                 "custom-call()", (2 + i) * ms, (3 + i) * ms)
+           for i, c in enumerate(("attn_q", "ffn_up", "lm_head"))]
+    return Reduced(w0=0.0, w1=10e9, modules=[[Event("jit_decode_fn(1)",
+                                                     ms, 9 * ms)]],
+                   ops=[ops], spans=[])
+
+
+def test_a_second_lm_architecture_needs_no_edit(tmp_path, monkeypatch):
+    """Add a DeepSeekMoE configuration (no head_dim, qk-norm off), its
+    stand-in reference, a traffic mix and a cell as new files and manifest
+    entries only: the LM driver checks the file against the program's
+    config through the reference's ``program_fields``, the readers of
+    decode work read None where the reference counts none, and no file
+    that was there changes."""
+    from types import SimpleNamespace
+
+    import work
+    bench = tmp_path / "benchmarks" / "chip"
+    shutil.copytree(HERE, bench, ignore=shutil.ignore_patterns(
+        "__pycache__", "tests", "testdata"))
+    before = _hashes(bench)
+    cfg = {"name": "deepseek-moe-smoke", "kind": "lm",
+           "source": "https://huggingface.co/deepseek-ai/deepseek-moe-16b-base",
+           "program_config": "deepseek-moe-16b", "program_smoke": True,
+           "reference": "deepseek_moe_stub", "hidden_size": 64,
+           "intermediate_size": 128, "moe_intermediate_size": 32,
+           "num_hidden_layers": 2, "num_attention_heads": 4,
+           "num_key_value_heads": 4, "n_routed_experts": 8,
+           "num_experts_per_tok": 3, "n_shared_experts": 1,
+           "vocab_size": 256, "max_position_embeddings": 128,
+           "rope_theta": 500000.0, "qk_norm": False, "reduced": {},
+           "limits": {"served_gap": 2.0}}
+    (bench / "configs" / "deepseek-moe-smoke.json").write_text(
+        json.dumps(cfg))
+    (bench / "refs" / "deepseek_moe_stub.py").write_text(STUB_REF)
+    (bench / "traffic" / "chat_short.json").write_text(json.dumps(dict(
+        manifest.cell("qwen3-chat").traffic, prompt_lens=[16, 32],
+        prompt_p=[0.5, 0.5], max_batch=2, max_seq=128,
+        output={"dist": "uniform", "min": 1, "max": 64})))
+    m = json.loads(json.dumps(M))
+    m["configs"].append({"name": cfg["name"], "source": cfg["source"],
+                         "file": "benchmarks/chip/configs/"
+                                 "deepseek-moe-smoke.json",
+                         "reduced": [], "why": "x"})
+    m["workloads"].append({"name": "deepseek-chat", "config": cfg["name"],
+                           "traffic": "chat_short", "chips": 1, "why": "x"})
+    for e in m["end_to_end"] + m["per_layer"]:
+        if "qwen3-chat" in e.get("workloads", []):
+            e["workloads"].append("deepseek-chat")
+    monkeypatch.setattr(manifest, "HERE", str(bench))
+    monkeypatch.setattr(manifest, "ROOT", str(tmp_path))
+
+    c = manifest.cell("deepseek-chat", m)
+    assert "head_dim" not in c.config and c.config["qk_norm"] is False
+    lm = manifest.load_module("drivers", c.config["kind"])
+    pc = lm.program_config(c.config, c.traffic)
+    assert (pc.family, pc.qk_norm, pc.max_seq) == ("moe", False, 128)
+    with pytest.raises(ValueError, match="deepseek-moe-16b"):
+        lm.program_config(dict(c.config, num_attention_heads=8), c.traffic)
+
+    steps = [SimpleNamespace(t0=1.0, contexts=[40, 50]),
+             SimpleNamespace(t0=2.0, contexts=[41])]
+    decode = [p["name"] for p in c.per_layer
+              if p["name"] == "decode_mfu_pct"
+              or p["name"].startswith("bitserial_matmul_roofline.")]
+    assert len(decode) == 5
+    for config, reads in ((c.config, False),
+                          (manifest.cell("qwen3-chat", m).config, True)):
+        ctx = {"trace": _decode_trace(), "peaks": work.peaks("TPU v5 lite"),
+               "rec": {"kind": "lm", "config": config, "steps": steps,
+                       "t_w0": 0.0, "t_w1": 10.0}}
+        for name in decode:
+            got = manifest.load_module("metrics", name).read(ctx)
+            assert (got is not None) == reads, (config["name"], name)
+
+    after = _hashes(bench)
+    assert {k: v for k, v in after.items() if k in before} == before

@@ -144,7 +144,9 @@ def test_class_rooflines_split_the_decode_roofline(tmp_path, monkeypatch):
     def least(names):
         return sum(count * work.least_time(*work.linear(rows, k, n), pk)
                    for rows in (2, 1)
-                   for name, k, n, count in work.lm_linears(rec["config"])
+                   for name, k, n, count in manifest.load_module(
+                       "refs", rec["config"]["reference"]).decode_linears(
+                           rec["config"])
                    if name in names)
 
     want = {"attn": (least({"attn_q", "attn_k", "attn_v", "attn_o"}), 3e-6),

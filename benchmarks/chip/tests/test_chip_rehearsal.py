@@ -25,3 +25,14 @@ def test_sound_run_is_correct(kind, capsys):
 def test_fault_is_caught(kind, fault):
     rec = rehearsal.rehearse(kind, fault=fault)
     assert not rec["correct"], rec["checks"]
+
+
+def test_cnn_window_keeps_calls_in_flight_and_waits_for_each():
+    """The CNN window dispatches ahead of the call it waits for, and
+    closes only once every call it dispatched has ended."""
+    rec = rehearsal.rehearse("cnn", seconds=1.0)
+    assert rec["in_flight"] > 1
+    assert rec["calls"] > rec["in_flight"]
+    assert rec["attempted"] == rec["calls"] * rec["batch"]
+    assert rec["window_s"] >= rec["seconds"]
+    assert rec["correct"], rec["checks"]

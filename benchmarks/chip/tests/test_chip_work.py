@@ -6,9 +6,12 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import linear_classes  # noqa: E402
+import manifest  # noqa: E402
+import readers  # noqa: E402
 import work  # noqa: E402
 
-QWEN = {"hidden_size": 2048, "intermediate_size": 6144,
+QWEN = {"reference": "qwen3", "hidden_size": 2048, "intermediate_size": 6144,
         "num_hidden_layers": 28, "num_attention_heads": 16,
         "num_key_value_heads": 8, "head_dim": 128, "vocab_size": 151936}
 
@@ -42,9 +45,44 @@ def test_decode_step_counts():
     attn_ops = 28 * 4 * (10 + 20) * 16 * 128
     assert ops == 2 * 2 * lin_params + attn_ops
     lin_bytes = sum(c * (k * n + 2 * k + 2 * n * 2)
-                    for _, k, n, c in work.lm_linears(QWEN))
+                    for _, k, n, c in manifest.load_module(
+                        "refs", "qwen3").decode_linears(QWEN))
     kv_bytes = 28 * 2 * (10 + 20) * 8 * 128 * 2
     assert nbytes == lin_bytes + kv_bytes
+
+
+def _steps_record():
+    """Four steps in a 10 s window, one of them empty, and one after it."""
+    from types import SimpleNamespace
+    steps = [SimpleNamespace(t0=t, contexts=ctx) for t, ctx in (
+        (1.0, [600, 700]), (2.0, [601]), (3.0, []),
+        (4.0, [1024, 2048, 3000]), (11.0, [5]))]
+    return {"kind": "lm", "config": dict(QWEN), "steps": steps,
+            "t_w0": 0.0, "t_w1": 10.0}
+
+
+# Read on the tree where work.py still counted the Qwen3 linears and
+# attention itself; the reference module's counts must give them exactly.
+PINNED = {
+    "decode_step": (6888685568.0, 1727829504.0),
+    "decode": 0.006316442725274726,
+    "attn": 0.0012947561025641026,
+    "mlp": 0.0038796471794871794,
+    "lm_head": 0.0011420394432234433,
+}
+
+
+@pytest.mark.parametrize("what", sorted(PINNED))
+def test_counts_from_the_reference_module_are_unchanged(what):
+    pk = work.peaks("TPU v5 lite")
+    rec = _steps_record()
+    if what == "decode_step":
+        got = work.decode_step(QWEN, [10, 20])
+    elif what == "decode":
+        got = readers.decode_least_s(rec, pk)
+    else:
+        got = linear_classes.least_s(rec, pk, linear_classes.CLASSES[what])
+    assert got == PINNED[what]
 
 
 def test_least_time_and_peaks():

@@ -22,7 +22,8 @@ the window), the window (``seconds``) and the tail (``tail_s`` after it,
 so that requests due late in the window still see the same load).
 
 ``cnn_closed_loop``: back-to-back calls of one ``batch``, cycling over
-``distinct_batches`` inputs.
+``distinct_batches`` inputs, with about ``ahead_s`` seconds of calls
+dispatched ahead of the one waited for.
 """
 from __future__ import annotations
 

@@ -6,8 +6,13 @@ Work is the logical operation, whatever implements it:
   M the ACTIVE rows (padding rows and empty batch slots count nothing);
 - bytes: the weights at Pw bits, the activations at Pa bits, and the
   outputs in the model's bfloat16;
-- attention during decode: the key and value bytes of the VALID cache
-  positions only, and 4*ctx*H*Dh operations (scores and weighted sum).
+- attention during decode: the bytes of the VALID cache positions only,
+  and the operations of scores and weighted sum over them.
+
+No LM architecture is counted here: an LM configuration's linears and
+its decode attention come from its own reference module
+(``decode_linears``, ``decode_attention``; see manifest.py), where each
+architecture states its shapes.
 
 A route change (bit-serial planes, int8, bf16) leaves these counts
 unchanged, and a change that skips padded rows or dead cache positions
@@ -19,6 +24,8 @@ from __future__ import annotations
 
 import json
 import os
+
+import manifest
 
 OUT_BYTES = 2          # bfloat16 outputs
 
@@ -56,46 +63,43 @@ def least_time(ops: float, nbytes: float, pk: dict) -> float:
     return max(ops / pk["int8_ops"], nbytes / pk["hbm_bytes_per_s"])
 
 
-# -- a Qwen3-style decoder (Hugging Face config names) ------------------------
+# -- a language model, by its reference module ------------------------------
 
-def lm_linears(c: dict) -> list[tuple[str, int, int, int]]:
-    """(name, K, N, count) of every linear of one token's forward pass."""
-    d, f = c["hidden_size"], c["intermediate_size"]
-    h, kv, dh = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["head_dim"])
-    n = c["num_hidden_layers"]
-    return [("attn_q", d, h * dh, n), ("attn_k", d, kv * dh, n),
-            ("attn_v", d, kv * dh, n), ("attn_o", h * dh, d, n),
-            ("ffn_gate", d, f, n), ("ffn_up", d, f, n),
-            ("ffn_down", f, d, n), ("lm_head", d, c["vocab_size"], 1)]
+def lm_decode(c: dict):
+    """The reference module of LM configuration ``c`` where it states its
+    decode work (``decode_linears`` and ``decode_attention``; the contract
+    is in manifest.py), else None."""
+    ref = manifest.load_module("refs", c["reference"])
+    if hasattr(ref, "decode_linears") and hasattr(ref, "decode_attention"):
+        return ref
+    return None
 
 
 def lm_linear_work(c: dict, rows: int, a_bits: int = 8, w_bits: int = 8):
-    """(ops, bytes) of every linear of one step over ``rows`` active rows."""
+    """(ops, bytes) of every linear of one step over ``rows`` active rows;
+    None where the reference states no decode work."""
+    ref = lm_decode(c)
+    if ref is None:
+        return None
     ops = nbytes = 0.0
-    for _, k, n, count in lm_linears(c):
+    for _, k, n, count in ref.decode_linears(c):
         o, b = linear(rows, k, n, a_bits, w_bits)
         ops += o * count
         nbytes += b * count
     return ops, nbytes
 
 
-def decode_attention(c: dict, ctx: int, kv_bytes: int = 2):
-    """(ops, bytes) of one row's decode attention over ``ctx`` valid
-    positions, all layers."""
-    h, kv, dh = (c["num_attention_heads"], c["num_key_value_heads"],
-                 c["head_dim"])
-    n = c["num_hidden_layers"]
-    return n * 4 * ctx * h * dh, n * 2 * ctx * kv * dh * kv_bytes
-
-
 def decode_step(c: dict, contexts: list[int], a_bits: int = 8,
                 w_bits: int = 8):
     """(ops, bytes) of one batched decode step; ``contexts`` holds the
-    valid cache length of each active row (its own token included)."""
+    valid cache length of each active row (its own token included).
+    None where the reference states no decode work."""
+    ref = lm_decode(c)
+    if ref is None:
+        return None
     ops, nbytes = lm_linear_work(c, len(contexts), a_bits, w_bits)
     for ctx in contexts:
-        o, b = decode_attention(c, ctx)
+        o, b = ref.decode_attention(c, ctx)
         ops += o
         nbytes += b
     return ops, nbytes
